@@ -62,10 +62,14 @@ def run(ci: bool = True, layers: int = 4, workers: int = None,
 
     results = {}
     proofs = {}
+    # a chip belongs to one process: on a TPU the process-backend
+    # sections are skipped (the engine refuses to spawn there)
+    fleet = not KOPS.on_tpu()
     runs = (("sequential", 1, "thread"),
-            ("parallel_threads", workers, "thread"),
-            ("sequential_fleet", 1, "process"),
-            ("parallel", workers, "process"))
+            ("parallel_threads", workers, "thread"))
+    if fleet:
+        runs += (("sequential_fleet", 1, "process"),
+                 ("parallel", workers, "process"))
     for label, n_workers, backend in runs:
         eng = ProverEngine(cfgs, weights, params, weight_cache=cache,
                            workers=n_workers, backend=backend)
@@ -132,63 +136,67 @@ def run(ci: bool = True, layers: int = 4, workers: int = None,
         else:
             os.environ["NANOZK_KERNEL_PATH"] = ambient
 
-    # -- warm-service scenario: N queries through ONE resident ProofService
-    # (the persistent serving daemon: engine + process fleet + weight cache
-    # stay resident, so query 1 pays spawn/jit/setup and the rest don't).
     from repro import api
-    n_service_queries = 3
-    service_rng = np.random.default_rng(1)
-    svc_queries = [
-        np.clip(np.round(service_rng.normal(0, 0.5,
-                                            (cfg.d_pad, cfg.seq)) * 256),
-                -32768, 32767).astype(np.int64)
-        for _ in range(n_service_queries)]
     policy = api.VerifyPolicy(pcs_queries=queries)
-    with api.ProofService(cfgs, weights, default_queries=queries,
-                          workers=workers, backend="process") as svc:
-        t0 = time.time()
-        att0 = svc.attest(svc_queries[0], policy)
-        t_cold = time.time() - t0          # spawn + jit warmup + first query
-        t0 = time.time()
-        for q in svc_queries[1:]:
-            svc.attest(q, policy)
-        t_warm = (time.time() - t0) / (n_service_queries - 1)
-    wire_v2 = len(att0.to_bytes(2))       # framed + deduplicated (default)
-    wire_v1 = len(att0.to_bytes(1))       # legacy envelope, inline paths
-    n_proved = max(1, len(att0.proved_layers))
-    results["service"] = {
-        "backend": "process",
-        "workers": workers,
-        "n_queries": n_service_queries,
-        "cold_first_query_seconds": t_cold,
-        "warm_seconds_per_query": t_warm,
-        "cold_queries_per_sec": 1.0 / t_cold,
-        "warm_queries_per_sec": 1.0 / t_warm,
-        "cold_over_warm": t_cold / t_warm,
-        "attestation_wire_bytes": wire_v2,
-        "attestation_wire_bytes_v1": wire_v1,
-        "wire_kb_per_layer": wire_v2 / n_proved / 1024,
-        "wire_kb_per_layer_v1": wire_v1 / n_proved / 1024,
-    }
-    print(f"attestation wire: v2 {wire_v2 / n_proved / 1024:.1f} KB/layer "
-          f"(v1 envelope {wire_v1 / n_proved / 1024:.1f} KB/layer)",
-          flush=True)
-    print(f"resident ProofService ({workers} process workers): cold "
-          f"{t_cold:.1f}s/query -> warm {t_warm:.1f}s/query "
-          f"({t_cold / t_warm:.2f}x, {1.0 / t_warm:.3f} queries/sec warm)",
-          flush=True)
-    # headline: wall-clock scaling of the proving fleet (1 -> N workers,
-    # same process-backed architecture).  Also report parallel vs the
-    # in-process sequential loop — on a box this small (cpu_count cores)
-    # the in-process prover already soaks up the idle core via XLA
-    # intra-op threads, so that ratio is hardware-capped near 1.
-    speedup = (results["sequential_fleet"]["prove_seconds"]
-               / results["parallel"]["prove_seconds"])
-    speedup_vs_inprocess = (results["sequential"]["prove_seconds"]
-                            / results["parallel"]["prove_seconds"])
-    print(f"fleet scaling 1->{workers} workers: {speedup:.2f}x "
-          f"(vs in-process sequential: {speedup_vs_inprocess:.2f}x), "
-          f"identical transcripts: {identical}", flush=True)
+    speedup = speedup_vs_inprocess = None
+    if fleet:
+        # -- warm-service scenario: N queries through ONE resident
+        # ProofService (the persistent serving daemon: engine + process
+        # fleet + weight cache stay resident, so query 1 pays
+        # spawn/jit/setup and the rest don't).
+        n_service_queries = 3
+        service_rng = np.random.default_rng(1)
+        svc_queries = [
+            np.clip(np.round(service_rng.normal(0, 0.5,
+                                                (cfg.d_pad, cfg.seq)) * 256),
+                    -32768, 32767).astype(np.int64)
+            for _ in range(n_service_queries)]
+        with api.ProofService(cfgs, weights, default_queries=queries,
+                              workers=workers, backend="process") as svc:
+            t0 = time.time()
+            att0 = svc.attest(svc_queries[0], policy)
+            t_cold = time.time() - t0   # spawn + jit warmup + first query
+            t0 = time.time()
+            for q in svc_queries[1:]:
+                svc.attest(q, policy)
+            t_warm = (time.time() - t0) / (n_service_queries - 1)
+        wire_v2 = len(att0.to_bytes(2))   # framed + deduplicated (default)
+        wire_v1 = len(att0.to_bytes(1))   # legacy envelope, inline paths
+        n_proved = max(1, len(att0.proved_layers))
+        results["service"] = {
+            "backend": "process",
+            "workers": workers,
+            "n_queries": n_service_queries,
+            "cold_first_query_seconds": t_cold,
+            "warm_seconds_per_query": t_warm,
+            "cold_queries_per_sec": 1.0 / t_cold,
+            "warm_queries_per_sec": 1.0 / t_warm,
+            "cold_over_warm": t_cold / t_warm,
+            "attestation_wire_bytes": wire_v2,
+            "attestation_wire_bytes_v1": wire_v1,
+            "wire_kb_per_layer": wire_v2 / n_proved / 1024,
+            "wire_kb_per_layer_v1": wire_v1 / n_proved / 1024,
+        }
+        print(f"attestation wire: v2 {wire_v2 / n_proved / 1024:.1f} "
+              f"KB/layer (v1 envelope {wire_v1 / n_proved / 1024:.1f} "
+              "KB/layer)", flush=True)
+        print(f"resident ProofService ({workers} process workers): cold "
+              f"{t_cold:.1f}s/query -> warm {t_warm:.1f}s/query "
+              f"({t_cold / t_warm:.2f}x, {1.0 / t_warm:.3f} queries/sec "
+              "warm)", flush=True)
+        # headline: wall-clock scaling of the proving fleet (1 -> N
+        # workers, same process-backed architecture).  Also report
+        # parallel vs the in-process sequential loop — on a box this small
+        # (cpu_count cores) the in-process prover already soaks up the
+        # idle core via XLA intra-op threads, so that ratio is
+        # hardware-capped near 1.
+        speedup = (results["sequential_fleet"]["prove_seconds"]
+                   / results["parallel"]["prove_seconds"])
+        speedup_vs_inprocess = (results["sequential"]["prove_seconds"]
+                                / results["parallel"]["prove_seconds"])
+        print(f"fleet scaling 1->{workers} workers: {speedup:.2f}x "
+              f"(vs in-process sequential: {speedup_vs_inprocess:.2f}x), "
+              f"identical transcripts: {identical}", flush=True)
 
     # -- gateway scenario: N concurrent clients through the
     # AttestationGateway.  Round 1 is cold (fresh service: jit + weight
@@ -269,9 +277,9 @@ def run(ci: bool = True, layers: int = 4, workers: int = None,
         "kernel_paths": kernel_results,
         "sequential": results["sequential"],
         "parallel_threads": results["parallel_threads"],
-        "sequential_fleet": results["sequential_fleet"],
-        "parallel": results["parallel"],
-        "service": results["service"],
+        "sequential_fleet": results.get("sequential_fleet"),
+        "parallel": results.get("parallel"),
+        "service": results.get("service"),
         "gateway": results["gateway"],
         "speedup": speedup,
         "speedup_vs_inprocess_sequential": speedup_vs_inprocess,

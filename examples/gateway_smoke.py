@@ -106,7 +106,9 @@ def main():
                 with open(os.path.join(td, f"att_{i}.bin"), "wb") as f:
                     f.write(wires[i])
                 np.save(os.path.join(td, f"q_{i}.npy"), queries[i])
-            env = dict(os.environ)
+            # the client verifies on its host CPU; the chip (if any)
+            # belongs to this server process
+            env = dict(os.environ, JAX_PLATFORMS="cpu")
             env["PYTHONPATH"] = os.path.join(
                 os.path.dirname(__file__), "..", "src") + os.pathsep + \
                 env.get("PYTHONPATH", "")

@@ -468,6 +468,13 @@ class Analyzer:
     def p_pjit(self, eqn, ins):
         return self.run_closed(eqn.params["jaxpr"], ins)
 
+    p_jit = p_pjit          # the primitive's name from JAX 0.7 on
+
+    def p_roll(self, eqn, ins):
+        # a lane rotation keeps the interval but moves elements: a fresh
+        # value, so select_n refinement never pairs it with its source
+        return [AbsVal(ins[0].lo, ins[0].hi)]
+
     def p_custom_jvp_call(self, eqn, ins):
         return self.run_closed(eqn.params["call_jaxpr"], ins)
 
@@ -480,6 +487,17 @@ class Analyzer:
         closed = eqn.params["jaxpr"]
         consts, carry, xs = ins[:nc], list(ins[nc:nc + ncar]), ins[nc + ncar:]
         ys_join: Optional[List[AbsVal]] = None
+        if eqn.params["length"] <= _MAX_LOOP_ITERS:
+            # short static trip count: interpret every iteration in order —
+            # exact for loop counters (a fori_loop index indexing a table)
+            for _it in range(eqn.params["length"]):
+                outs = self.run_closed(closed, consts + carry + list(xs))
+                carry, ys = list(outs[:ncar]), outs[ncar:]
+                ys_join = ys if ys_join is None else [
+                    _join(a, b) for a, b in zip(ys_join, ys)]
+            if ys_join is None:
+                ys_join = [self._clamped_top(v) for v in eqn.outvars[ncar:]]
+            return carry + ys_join
         for _it in range(_MAX_LOOP_ITERS):
             outs = self.run_closed(closed, consts + carry + list(xs))
             new_carry, ys = outs[:ncar], outs[ncar:]

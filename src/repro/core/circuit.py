@@ -874,6 +874,11 @@ def _lookup_table_sum(req: LookupReq, counts_info, beta, alpha
         t_vals = F.f4_from_base(F.f_from_int(support))
         return LK.table_inverse_sum(t_vals, counts, alpha)
     support, counts = counts_info
+    # The support's length varies with the data: zero-count padding to a
+    # power of two adds exact zeros to the sum and keeps the programs below
+    # to one shape per bucket instead of one per query.
+    pad = (1 << max(len(support) - 1, 0).bit_length()) - len(support)
+    support, counts = np.pad(support, (0, pad)), np.pad(counts, (0, pad))
     table = LUTS.table_q(req.table).astype(np.int64)
     t_vals = LK.combine_pair(F.f_from_int(support),
                              F.f_from_int(table[support]), beta)

@@ -31,7 +31,6 @@ assert P < 2**31
 TWO_ADICITY = 27
 _R = 2**32
 R_MOD_P = _R % P
-R2_MOD_P = (_R * _R) % P
 # -P^{-1} mod 2^32 (Montgomery constant)
 NEG_P_INV = (-pow(P, -1, _R)) % _R
 
@@ -127,25 +126,20 @@ def finv(a: jnp.ndarray) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # Montgomery encode/decode.
 # ---------------------------------------------------------------------------
-def to_mont(x: jnp.ndarray) -> jnp.ndarray:
-    """Standard-form uint32 (values < P) -> Montgomery form."""
-    return fmul(x.astype(_U32), jnp.asarray(_c(R2_MOD_P)))
+_R_INV_MOD_P = pow(_R, -1, P)
 
 
-def from_mont(a: jnp.ndarray) -> jnp.ndarray:
-    """Montgomery form -> standard-form uint32 in [0, P)."""
-    return fmul(a, jnp.asarray(_c(1)))
-
-
+# Host-side conversions: exact in int64 (both factors < 2^31), and with no
+# device program per array shape to compile.
 def f_from_int(x) -> jnp.ndarray:
     """numpy/int array (any signed ints) -> Montgomery Fp array."""
     arr = np.asarray(x, dtype=np.int64) % P
-    return to_mont(jnp.asarray(arr.astype(np.uint32)))
+    return jnp.asarray((arr * R_MOD_P % P).astype(np.uint32))
 
 
 def f_to_int(a: jnp.ndarray) -> np.ndarray:
     """Montgomery Fp array -> numpy int64 array of canonical values."""
-    return np.asarray(jax.device_get(from_mont(a)), dtype=np.int64)
+    return np.asarray(jax.device_get(a), dtype=np.int64) * _R_INV_MOD_P % P
 
 
 def fone(shape=()) -> jnp.ndarray:
@@ -230,13 +224,28 @@ def f4pow(a: jnp.ndarray, e: int) -> jnp.ndarray:
     return result
 
 
+_INV_CHUNK = 1 << 16
+
+
 @jax.jit
 def f4inv(a: jnp.ndarray) -> jnp.ndarray:
     """Inverse in Fp4 via the norm map: a^-1 = conj / N(a).
 
     N(a) = a * a^p * a^{p^2} * a^{p^3} lies in Fp. Frobenius on the binomial
     basis is coefficient-wise: (x^i)^{p^j} = W4^{i(p^j-1)/4} x^i.
+
+    Larger arrays are inverted 2^16 elements at a time: in one piece, the
+    exponentiation chain's temporaries took 11 GiB at 2^25 elements on a
+    TPU v5e.
     """
+    n = a.size // 4
+    if n > _INV_CHUNK and n % _INV_CHUNK == 0:
+        return jax.lax.map(_f4inv, a.reshape(-1, _INV_CHUNK, 4)
+                           ).reshape(a.shape)
+    return _f4inv(a)
+
+
+def _f4inv(a: jnp.ndarray) -> jnp.ndarray:
     shape = jnp.shape(a)[:-1]
     # Frobenius twists: gamma_j[i] = W4^{i*(p^j-1)/4} (precomputed ints).
     conj = f4one(shape)
@@ -305,9 +314,6 @@ ANALYSIS_BOUNDS = {
                  args=(("fp", (8,)), ("fp", (8,))), out="fp"),
     "fneg": dict(fn=lambda a: fneg(a), args=(("fp", (8,)),), out="fp"),
     "finv": dict(fn=lambda a: finv(a), args=(("fp", (8,)),), out="fp"),
-    "to_mont": dict(fn=lambda x: to_mont(x), args=(("fp", (8,)),), out="fp"),
-    "from_mont": dict(fn=lambda a: from_mont(a),
-                      args=(("fp", (8,)),), out="fp"),
     "f4_from_base": dict(fn=lambda a: f4_from_base(a),
                          args=(("fp", (8,)),), out="fp"),
     "f4mul": dict(fn=lambda a, b: f4mul(a, b),

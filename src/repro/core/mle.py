@@ -42,17 +42,33 @@ def fsum(x: jnp.ndarray, axis: int = 0) -> jnp.ndarray:
     return x[0]
 
 
+_EQ_SPLIT = 12
+
+
 @jax.jit
 def eq_points(r: jnp.ndarray) -> jnp.ndarray:
-    """eq(r, x) for all x in {0,1}^m -> (2^m, 4). r: (m, 4) Fp4."""
+    """eq(r, x) for all x in {0,1}^m -> (2^m, 4). r: (m, 4) Fp4.
+
+    eq factors over the variables, and exact arithmetic makes the grouping
+    of the products invisible.  Up to ``_EQ_SPLIT`` variables the table is
+    one scan over r, bit j of x choosing r_j or 1 - r_j; above it, the
+    outer product of the tables of r's leading and trailing halves.  Both
+    keep the program short: the doubling chain this replaces compiled in
+    time growing with m (~50 s at m = 20 for a TPU v5e)."""
     m = r.shape[0]
-    out = F.f4one((1,))
-    for j in range(m - 1, -1, -1):
-        rj = r[j]
-        one_minus = F.f4sub(F.f4one(()), rj)
-        lo = F.f4mul(out, jnp.broadcast_to(one_minus, out.shape))
-        hi = F.f4mul(out, jnp.broadcast_to(rj, out.shape))
-        out = jnp.concatenate([lo, hi], axis=0)
+    if m > _EQ_SPLIT:
+        hi, lo = eq_points(r[:m // 2]), eq_points(r[m // 2:])
+        return F.f4mul(hi[:, None, :], lo[None, :, :]).reshape(-1, 4)
+    x = jnp.arange(1 << m, dtype=jnp.uint32)[:, None]
+
+    def bind(acc, jr):
+        j, rj = jr
+        bit = (x >> (m - 1 - j)) & 1
+        return F.f4mul(acc, jnp.where(bit == 1, rj,
+                                      F.f4sub(F.f4one(()), rj))), None
+
+    out, _ = jax.lax.scan(bind, F.f4one((1 << m,)),
+                          (jnp.arange(m, dtype=jnp.uint32), r))
     return out
 
 
@@ -102,15 +118,17 @@ def eq_eval(r: jnp.ndarray, rho: jnp.ndarray) -> jnp.ndarray:
     """eq~(r, rho) = prod_j (r_j rho_j + (1-r_j)(1-rho_j)) over Fp4.
 
     Order-symmetric, so it is convention-independent as long as r and rho
-    pair up the same variables.
+    pair up the same variables.  The m factors are computed as one vector
+    and multiplied in order under ``lax.scan``: an unrolled chain of m
+    Fp4 products fuses into a program whose run time grows ~4.5x per
+    variable on CPU (seconds at m=14, never finishing at a GPT-2-small weight
+    commitment's m=25).
     """
-    one = F.f4one(())
-    acc = one
-    for j in range(r.shape[0]):
-        rj, sj = r[j], rho[j]
-        term = F.f4add(F.f4mul(rj, sj),
-                       F.f4mul(F.f4sub(one, rj), F.f4sub(one, sj)))
-        acc = F.f4mul(acc, term)
+    one = F.f4one(r.shape[:1])
+    terms = F.f4add(F.f4mul(r, rho),
+                    F.f4mul(F.f4sub(one, r), F.f4sub(one, rho)))
+    acc, _ = jax.lax.scan(lambda acc, t: (F.f4mul(acc, t), None),
+                          F.f4one(()), terms)
     return acc
 
 

@@ -155,8 +155,7 @@ def _rs_encode(rows: jnp.ndarray, blowup: int) -> jnp.ndarray:
         padded = jnp.concatenate(
             [rows, jnp.zeros(rows.shape[:-1] + (n - c,), dtype=rows.dtype)],
             axis=-1)
-        flat = padded.reshape(-1, n)
-        out = KOPS.ntt(flat, block=math.gcd(flat.shape[0], 8))
+        out = KOPS.ntt(padded.reshape(-1, n))
         return out.reshape(rows.shape[:-1] + (n,))
     return N.rs_encode(rows, blowup)
 
@@ -284,22 +283,39 @@ def _gamma_powers(gamma: jnp.ndarray, k: int) -> jnp.ndarray:
     return ws
 
 
-@functools.partial(jax.jit, static_argnames=("t", "n_tot"))
-def _bucket_e_impl(sufs: jnp.ndarray, ws_ext: jnp.ndarray, widx: jnp.ndarray,
-                   los: jnp.ndarray, t: int, n_tot: int) -> jnp.ndarray:
-    """Scatter one suffix-length bucket of claim groups into a (n_tot, 4)
-    buffer.  sufs: (G, Mx, t, 4) group-member suffixes (zero-padded slots),
-    ws_ext: (k+1, 4) gamma powers with a trailing zero row, widx: (G, Mx)
-    per-slot claim index (padding slots point at the zero row, so they
-    contribute exactly nothing), los: (G,) slice offsets.  Groups within a
-    bucket share t but have distinct prefixes, so their slices are disjoint
-    and the scatter is collision-free."""
-    tabs = jax.vmap(jax.vmap(eq_points))(sufs)           # (G, Mx, 2^t, 4)
-    ws = ws_ext[widx]                                    # (G, Mx, 4)
-    seg = fsum(F.f4mul(ws[:, :, None, :], tabs), axis=1)  # (G, 2^t, 4)
-    rows = (los[:, None] + jnp.arange(1 << t)[None, :]).reshape(-1)
-    e = jnp.zeros((n_tot, 4), jnp.uint32)
-    return e.at[rows].set(seg.reshape(-1, 4), unique_indices=True)
+_E_CHUNK_LOG = 16      # e_vec entries written per loop step: 2^16
+
+
+@functools.partial(jax.jit, static_argnames=("t",), donate_argnums=(0,))
+def _bucket_e_impl(e: jnp.ndarray, sufs: jnp.ndarray, ws_ext: jnp.ndarray,
+                   widx: jnp.ndarray, los: jnp.ndarray, t: int) -> jnp.ndarray:
+    """Add one suffix-length bucket of claim groups into e (n_tot, 4),
+    in place.  sufs: (G, Mx, t, 4) group-member suffixes (zero-padded
+    slots), ws_ext: (k+1, 4) gamma powers with a trailing zero row, widx:
+    (G, Mx) per-slot claim index (padding slots point at the zero row, so
+    they contribute exactly nothing), los: (G,) slice offsets.  Groups
+    within a bucket share t but have distinct prefixes, so their slices
+    are disjoint.
+
+    A group's 2^t slice is written in chunks of 2^c: eq(suf, x_hi x_lo) =
+    eq(suf[:h], x_hi) * eq(suf[h:], x_lo), so a chunk is one Fp4 scalar
+    per member times that member's 2^c-long low table.  No 2^t table is
+    ever built: at t = 25 one took 11 GiB of temporaries on a TPU v5e."""
+    c = min(t, _E_CHUNK_LOG)
+    h = t - c
+    ws = ws_ext[widx]                                         # (G, Mx, 4)
+    tables = jax.vmap(jax.vmap(eq_points))
+    lo = tables(sufs[:, :, h:])                               # (G, Mx, 2^c, 4)
+    whi = F.f4mul(ws[:, :, None, :], tables(sufs[:, :, :h]))  # (G, Mx, 2^h, 4)
+
+    def put(i, e):
+        g, j = i >> h, i & ((1 << h) - 1)
+        chunk = fsum(F.f4mul(whi[g, :, j][:, None, :], lo[g]), axis=0)
+        at = (los[g] + (j << c), 0)
+        cur = jax.lax.dynamic_slice(e, at, (1 << c, 4))
+        return jax.lax.dynamic_update_slice(e, F.f4add(cur, chunk), at)
+
+    return jax.lax.fori_loop(0, sufs.shape[0] << h, put, e)
 
 
 def _build_e_vec(n_tot: int, pts_np: Sequence[np.ndarray],
@@ -308,15 +324,17 @@ def _build_e_vec(n_tot: int, pts_np: Sequence[np.ndarray],
 
     Claims are grouped by the slice their const-bit prefix addresses, then
     groups are bucketed by suffix length t: each bucket is ONE jitted
-    vmap-eq + scatter dispatch (distinct prefixes within a bucket address
-    disjoint slices).  Values are identical to the naive sequential fold
-    (exact mod-p arithmetic is reduction-order-free and zero-weight padding
-    slots are exact additive identities), but the work drops from k*N to
-    the sum of the touched slice sizes, in a handful of dispatches."""
+    dispatch that adds into e_vec in place (distinct prefixes within a
+    bucket address disjoint slices).  Values are identical to the naive
+    sequential fold (exact mod-p arithmetic is reduction-order-free and
+    zero-weight padding slots are exact additive identities), but the work
+    drops from k*N to the sum of the touched slice sizes, in a handful of
+    dispatches."""
     m = n_tot.bit_length() - 1
     k = len(pts_np)
+    e_vec = jnp.zeros((n_tot, 4), jnp.uint32)
     if k == 0:
-        return jnp.zeros((n_tot, 4), jnp.uint32)
+        return e_vec
     ws_ext = jnp.concatenate(
         [_gamma_powers(gamma, k), jnp.zeros((1, 4), jnp.uint32)])
     groups: Dict[Tuple[int, int], List[int]] = {}
@@ -329,7 +347,6 @@ def _build_e_vec(n_tot: int, pts_np: Sequence[np.ndarray],
     buckets: Dict[int, List[Tuple[int, List[int]]]] = {}
     for (t, idx), members in groups.items():
         buckets.setdefault(t, []).append((idx, members))
-    e_vec = None
     for t in sorted(buckets):
         glist = sorted(buckets[t])
         G = len(glist)
@@ -342,9 +359,8 @@ def _build_e_vec(n_tot: int, pts_np: Sequence[np.ndarray],
             for j, i in enumerate(members):
                 sufs[g, j] = pts_np[i][m - t:]
                 widx[g, j] = i
-        part = _bucket_e_impl(jnp.asarray(sufs), ws_ext, jnp.asarray(widx),
-                              jnp.asarray(los), t, n_tot)
-        e_vec = part if e_vec is None else F.f4add(e_vec, part)
+        e_vec = _bucket_e_impl(e_vec, jnp.asarray(sufs), ws_ext,
+                               jnp.asarray(widx), jnp.asarray(los), t)
     return e_vec
 
 

@@ -1,18 +1,21 @@
-"""Jit'd wrappers selecting Pallas kernels (TPU) or interpret mode (CPU).
+"""Jit'd wrappers selecting compiled Pallas kernels (TPU) or interpret mode.
 
-On TPU the kernels run compiled; on CPU (this container) interpret=True
-executes the kernel bodies in Python for correctness validation — the
-mode the test suite sweeps shapes/dtypes in. `on_tpu()` picks per-backend.
+On TPU the kernels run compiled; on CPU interpret=True executes the kernel
+bodies for correctness validation — the mode the test suite sweeps shapes
+in. ``on_tpu()`` picks per backend.
 
 Kernel path switch
 ------------------
-``NANOZK_KERNEL_PATH`` selects the *prover-side* implementation:
+The prover has two implementations:
 
-* ``ref`` (default) — the pure-jnp reference path in ``repro.core``.
-* ``fused`` — the Pallas kernel path: fused sum-check rounds
+* ``fused`` — the Pallas kernel path: sum-check round kernels
   (``sumcheck_round.py``), kernel-batched Poseidon2 Merkle hashing,
   modmatmul-backed partial evaluations, and the NTT kernel for RS
-  encoding.
+  encoding.  The default on TPU, where every kernel runs compiled.
+* ``ref`` — the pure-jnp reference path in ``repro.core``.  The default
+  everywhere else, and the oracle the kernel path is checked against.
+
+``NANOZK_KERNEL_PATH=ref|fused`` overrides the platform default.
 
 The switch is environment-driven and deliberately independent of
 ``VerifyPolicy``: it changes *how* proofs are computed, never *what* is
@@ -37,12 +40,13 @@ KERNEL_PATHS = ("ref", "fused")
 
 
 def kernel_path() -> str:
-    """Active prover kernel path: 'ref' (jnp oracle) or 'fused' (Pallas)."""
-    p = os.environ.get("NANOZK_KERNEL_PATH", "ref").strip().lower()
+    """Active prover kernel path: 'ref' (jnp oracle) or 'fused' (Pallas);
+    by platform unless NANOZK_KERNEL_PATH names one."""
+    p = os.environ.get("NANOZK_KERNEL_PATH", "").strip().lower()
     if p and p not in KERNEL_PATHS:
         raise ValueError(
             f"NANOZK_KERNEL_PATH={p!r}: expected one of {KERNEL_PATHS}")
-    return p or "ref"
+    return p or ("fused" if on_tpu() else "ref")
 
 
 def use_fused() -> bool:
@@ -102,7 +106,8 @@ def _partial_rows_impl(mat, eq, interpret):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _partial_cols_impl(mat, eq, interpret):
-    return _mm.modmatmul(mat, eq, interpret=interpret)
+    # (eq^T mat^T)^T keeps the 4-wide Fp4 axis off the kernel's lane dim
+    return _mm.modmatmul(eq.T, mat.T, interpret=interpret).T
 
 
 def partial_eval_rows_mm(mat, r_rows, **kw):
@@ -159,6 +164,10 @@ ANALYSIS_ENTRIES = {
     "sumcheck_prove_rounds": _ae(
         lambda f0, f1, st: sumcheck_prove_rounds((f0, f1), st),
         ("fp", (8, 4)), ("fp", (8, 4)), ("fp", (16,))),
+    "sumcheck_prove_rounds_pallas": _ae(
+        lambda f0, f1, st: sumcheck_prove_rounds((f0, f1), st,
+                                                 force_pallas=True),
+        ("fp", (512, 4)), ("fp", (512, 4)), ("fp", (16,)), pallas=True),
     "partial_eval_rows_mm": _ae(lambda m, r: partial_eval_rows_mm(m, r),
                                 ("fp", (8, 8)), ("fp", (3, 4)), pallas=True),
     "partial_eval_cols_mm": _ae(lambda m, r: partial_eval_cols_mm(m, r),

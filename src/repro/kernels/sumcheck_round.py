@@ -1,25 +1,36 @@
-"""Pallas kernel: fully-fused sum-check round, batched over claims.
+"""Pallas kernels: sum-check rounds, batched over claims.
 
-One launch per round index computes — for a batch of K independent claims
-sharing (n, d) — the round polynomial g(0..d), the Fiat-Shamir transcript
-absorb of g, the challenge squeeze, AND the fold by that challenge, all in
-one VMEM residency.  This removes the per-round host round-trip of the jnp
-reference prover (``core/sumcheck.py``), whose cost is dispatch, not FLOPs:
-each reference round issues dozens of small ops plus a device->host sync
-for the challenge.
+Each round of the prover runs as three launches for a batch of K
+independent claims sharing (n, d): an evaluation launch computes the round
+polynomial g(0..d); a transcript launch absorbs g into each claim's
+Fiat-Shamir sponge and squeezes the challenge; a fold launch binds the
+variable at that challenge.  Evaluation and fold are gridded over (claim,
+row tile), so factors of any length stream through VMEM, with running
+partial sums of g in VMEM scratch.  The transcript launch depends only on
+(K, d), and loops one permutation over its chunks.  Nothing syncs to the
+host mid-prove.
+
+Layout: a factor of n Fp4 values is held as (K, 4, R, 128) — coefficient
+axis leading, R = max(n / 128, 1) rows of 128 lanes, lanes beyond n zero.
+For n >= 256 the (lo, hi) halves are whole rows; for n <= 128 the high
+half is brought to the low lanes by a lane rotation and the rest masked.
+All reductions are halving adds over rows and rotate-and-add over lanes,
+g and the transcript state ride as splat (1, 128) rows, and every value is
+elementwise on vregs — nothing reshapes, gathers or scatters across the
+minor dimension (the TPU compiler refuses those).
 
 Byte-identity contract: BabyBear/Fp4 arithmetic is exact mod p, so any
 evaluation/reduction order yields identical field values; the sponge
 schedule here (length tag, RATE-chunk adds, one permutation per chunk, one
 squeeze permutation per challenge) replicates ``core/transcript.py``
-element-for-element.  Transcripts produced by this kernel are therefore
+element-for-element.  Transcripts produced by these kernels are therefore
 byte-identical to the reference path — enforced by
 ``tests/test_kernel_parity.py`` and the golden wire vectors.
 
-The sponge state rides through the kernel as a (K, 16) operand: claim k's
-transcript enters as row k and leaves updated, so K claims from different
-layer proofs (independent transcripts by construction) batch into the same
-launch — the engine's ``SumcheckRoundBatcher`` exploits exactly this.
+The sponge state is a per-claim operand: claim k's transcript enters as
+row k and leaves updated, so K claims from different layer proofs
+(independent transcripts by construction) batch into the same launches —
+the engine's ``SumcheckRoundBatcher`` exploits exactly this.
 """
 from __future__ import annotations
 
@@ -30,11 +41,18 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import field as F
 from repro.core import poseidon2 as P2
+from repro.core import sumcheck as SC
 from repro.core import transcript as T
 from . import poseidon2_kernel as PK
+
+LANES = 128
+ROW_BLOCK = 64          # rows of 128 per factor half per grid step
+
+_W4 = np.uint32(F._W4M)
 
 
 def _mont(v: int) -> np.uint32:
@@ -43,197 +61,329 @@ def _mont(v: int) -> np.uint32:
     return np.uint32((v % F.P) * F._R % F.P)
 
 
-def _tree_sum(x: jnp.ndarray) -> jnp.ndarray:
-    """Exact mod-p halving-tree sum over axis 1 of (bk, n, 4); n power of 2."""
-    while x.shape[1] > 1:
-        h = x.shape[1] // 2
-        x = F.f4add(x[:, :h], x[:, h:])
-    return x[:, 0]
+# ---------------------------------------------------------------------------
+# Kernel bodies.  Fp4 values are 4-tuples of same-shape Fp arrays.
+# ---------------------------------------------------------------------------
+def _f4add(a, b):
+    return tuple(F.fadd(x, y) for x, y in zip(a, b))
 
 
-def _round_math(states, rcf, rcp, diag, vals, d: int, unroll: bool):
-    """The fused round body on traced values — single source of truth shared
-    by the Pallas kernel (refs in/out) and the interpret-mode direct call.
+def _f4sub(a, b):
+    return tuple(F.fsub(x, y) for x, y in zip(a, b))
 
-    vals: d factor views (bk, 2, half, 4) as (lo, hi) pairs.
-    Returns (g (bk, d+1, 4), folded list of (bk, half, 4), states (bk, 16)).
-    """
-    if unroll:
-        permute = lambda s: PK.permute_value(s, rcf, rcp, diag)
-    else:
-        # scan-based rounds keep the traced graph one-round-sized (full
-        # unrolling exploded XLA compile times ~40x)
-        permute = lambda s: PK.permute_value_scan(s, rcf, rcp, diag)
 
-    los = [v[:, 0] for v in vals]           # (bk, half, 4)
-    his = [v[:, 1] for v in vals]
-    diffs = [F.f4sub(h, l) for h, l in zip(his, los)]
+def _f4mul(a, b):
+    """Same product as ``F.f4mul`` (x^4 = W4), on coefficient tuples."""
+    m = F.fmul
+    a0, a1, a2, a3 = a
+    b0, b1, b2, b3 = b
+    return (
+        F.fadd(m(a0, b0), m(_W4, F.fadd(F.fadd(m(a1, b3), m(a2, b2)),
+                                        m(a3, b1)))),
+        F.fadd(F.fadd(m(a0, b1), m(a1, b0)),
+               m(_W4, F.fadd(m(a2, b3), m(a3, b2)))),
+        F.fadd(F.fadd(m(a0, b2), m(a1, b1)),
+               F.fadd(m(a2, b0), m(_W4, m(a3, b3)))),
+        F.fadd(F.fadd(m(a0, b3), m(a1, b2)), F.fadd(m(a2, b1), m(a3, b0))))
 
-    # g(t) for t = 0..d: evaluate each factor at X=t by repeated +diff.
-    cur = list(los)
-    evals = []
+
+def _halves(f_refs, n: int):
+    """(lo, hi) Fp4 tuples of every factor's current tile."""
+    los, his = [], []
+    for r in f_refs:
+        x = r[0]
+        if n > LANES:                    # (4, 2, rows, 128): whole rows
+            los.append(tuple(x[c, 0] for c in range(4)))
+            his.append(tuple(x[c, 1] for c in range(4)))
+            continue
+        half = n // 2                    # (4, 1, 128): split inside lanes
+        keep = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1) < half
+        zero = jnp.zeros((1, LANES), jnp.uint32)
+        los.append(tuple(jnp.where(keep, x[c], zero) for c in range(4)))
+        his.append(tuple(
+            jnp.where(keep, pltpu.roll(x[c], LANES - half, 1), zero)
+            for c in range(4)))
+    return los, his
+
+
+def _sum_rows(x):
+    """(rows, 128) -> (1, 128) exact mod-p halving sum over rows."""
+    while x.shape[0] > 1:
+        h = x.shape[0] // 2
+        x = F.fadd(x[:h], x[h:])
+    return x
+
+
+def _sum_lanes(x):
+    """(1, 128) -> (1, 128) with the mod-p sum of all lanes in every lane."""
+    s = LANES // 2
+    while s:
+        x = F.fadd(x, pltpu.roll(x, s, 1))
+        s //= 2
+    return x
+
+
+def _eval_kernel(*refs, d: int, n: int):
+    # refs: d factor tiles, then g (1, d+1, 4, 1, 128) and scratch acc
+    f_refs = refs[:d]
+    g_ref, acc_ref = refs[d:]
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    los, his = _halves(f_refs, n)
+    diffs = [_f4sub(h, lo) for h, lo in zip(his, los)]
+    cur = los
     for t in range(d + 1):
-        if t > 0:
-            cur = [F.f4add(x, dd) for x, dd in zip(cur, diffs)]
+        if t:
+            cur = [_f4add(x, dd) for x, dd in zip(cur, diffs)]
         prod = cur[0]
         for f in cur[1:]:
-            prod = F.f4mul(prod, f)
-        evals.append(_tree_sum(prod))       # (bk, 4)
-    g = jnp.stack(evals, axis=1)            # (bk, d+1, 4)
+            prod = _f4mul(prod, f)
+        for c in range(4):
+            acc_ref[t, c] = F.fadd(acc_ref[t, c], _sum_rows(prod[c]))
 
-    # Transcript absorb of g — mirrors transcript._absorb_impl exactly.
-    bk = g.shape[0]
-    n_abs = 4 * (d + 1)
-    flat = g.reshape(bk, n_abs)
-    pad = (-n_abs) % P2.RATE
-    if pad:
-        flat = jnp.concatenate(
-            [flat, jnp.zeros((bk, pad), jnp.uint32)], axis=1)
-    st = states
-    st = st.at[:, P2.RATE].set(F.fadd(st[:, P2.RATE], _mont(n_abs)))
-    for k in range(flat.shape[1] // P2.RATE):
-        chunk = flat[:, k * P2.RATE:(k + 1) * P2.RATE]
-        st = st.at[:, :P2.RATE].set(F.fadd(st[:, :P2.RATE], chunk))
-        st = permute(st)
-
-    # Challenge squeeze (transcript.challenge_f4: one permute, lanes 0..3)
-    # and fold by it — the folded factors never leave the fused residency.
-    st = permute(st)
-    c = st[:, None, :4]                     # (bk, 1, 4)
-    folded = [F.f4add(l, F.f4mul(jnp.broadcast_to(c, l.shape), dd))
-              for l, dd in zip(los, diffs)]
-    return g, folded, st
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _total():
+        for t in range(d + 1):
+            for c in range(4):
+                g_ref[0, t, c] = _sum_lanes(acc_ref[t, c])
 
 
-def _round_kernel(st_ref, rcf_ref, rcp_ref, diag_ref, *refs,
-                  d: int, unroll: bool):
-    # refs: d factor inputs (bk, 2, half, 4) as (lo, hi) pairs, then outputs:
-    #       g (bk, d+1, 4), d folded (bk, half, 4), new states (bk, 16)
-    ins = refs[:d]
-    g_ref = refs[d]
-    folded_outs = refs[d + 1:2 * d + 1]
-    st_out = refs[2 * d + 1]
-    vals = [r[...] for r in ins]            # read each factor ref ONCE
-    g, folded, st = _round_math(
-        st_ref[...], rcf_ref[...], rcp_ref[...], diag_ref[...][0],
-        vals, d, unroll)
-    g_ref[...] = g
-    st_out[...] = st
-    for i in range(d):
-        folded_outs[i][...] = folded[i]
+def _transcript_kernel(rc_ref, st_ref, g_ref, st_out, c_ref, *, d: int):
+    """Absorb g (transcript._absorb_impl schedule) and squeeze one Fp4
+    challenge (transcript.challenge_f4), on splat (1, 128) rows.  g_ref
+    holds g zero-padded to whole RATE chunks (RATE / 4 Fp4 values each);
+    each loop step adds one chunk, none in the squeeze step, and permutes,
+    so the permutation is compiled once."""
+    per = P2.RATE // 4
+    chunks = g_ref.shape[1] // per
+    s = [st_ref[0, i] for i in range(P2.WIDTH)]
+    s[P2.RATE] = F.fadd(s[P2.RATE], _mont(4 * (d + 1)))
+
+    def step(k, s):
+        row = per * jnp.minimum(k, chunks - 1)
+        live = k < chunks
+        chunk = [jnp.where(live, g_ref[0, row + e // 4, e % 4], 0)
+                 for e in range(P2.RATE)]
+        s = [F.fadd(a, b) for a, b in zip(s, chunk)] + list(s[P2.RATE:])
+        return tuple(PK.permute_lanes(s, rc_ref))
+
+    s = jax.lax.fori_loop(0, chunks + 1, step, tuple(s))
+    for i in range(P2.WIDTH):
+        st_out[0, i] = s[i]
+    for i in range(4):
+        c_ref[0, i] = s[i]
 
 
-@functools.partial(jax.jit, static_argnames=("pallas", "unroll"))
-def _launch_round(factors, states, pallas: bool, unroll: bool):
-    """One fused sum-check round for all K claims: g evals, transcript
-    absorb, challenge squeeze, fold.  Jitted per (K, n, d) — and the jit
-    cache is shared across *sum-checks*: every claim whose current length
-    is n hits the same compiled unit, so a whole layer proof needs only
-    one compile per (K, power-of-two, d).  On TPU the body is one Pallas
-    launch.
+def _fold_kernel(c_ref, *refs, d: int, n: int):
+    # refs: d factor tiles, then d folded tiles (1, 4, rows, 128)
+    c = tuple(c_ref[0, i] for i in range(4))         # splat (1, 128)
+    los, his = _halves(refs[:d], n)
+    for lo, hi, out in zip(los, his, refs[d:]):
+        folded = _f4add(lo, _f4mul(c, _f4sub(hi, lo)))
+        for i in range(4):
+            out[0, i] = folded[i]
 
-    Accepts single-claim shapes ((n, 4) factors, (16,) state) or batched
-    ((K, n, 4), (K, 16)); the batch axis is normalized inside the jit so
-    callers never pay an eager expand_dims.  Returns (g, folded factors,
-    new states, challenges (K, 4))."""
-    d = len(factors)
-    n = factors[0].shape[-2]
-    half = n // 2
-    vals = [f.reshape(-1, 2, half, 4) for f in factors]
-    states = states.reshape(-1, P2.WIDTH)
-    K = states.shape[0]
-    rcf, rcp, diag = PK.round_constants()
-    if not pallas:
-        # Interpret-mode execution of the SAME fused body, directly under
-        # the jit: one traced graph, one dispatch per round.  (Driving
-        # pl.pallas_call(interpret=True) here is semantically identical
-        # but its tracing overhead is ~5 s per launch — the parity tests
-        # cover the real pallas wiring on small shapes.)
-        g, folded, st = _round_math(states, rcf, rcp, diag, vals, d, unroll)
-        return g, tuple(folded), st, st[:, :4]
-    bk = PK._pick_block(K, 8)
-    rep = lambda shape: pl.BlockSpec(shape, lambda i: (0,) * len(shape))
-    in_specs = [pl.BlockSpec((bk, P2.WIDTH), lambda i: (i, 0)),
-                rep(tuple(rcf.shape)), rep(tuple(rcp.shape)),
-                rep(tuple(diag.shape))] + [
-        pl.BlockSpec((bk, 2, half, 4), lambda i: (i, 0, 0, 0))
-        for _ in range(d)]
-    out_specs = [pl.BlockSpec((bk, d + 1, 4), lambda i: (i, 0, 0))] + [
-        pl.BlockSpec((bk, half, 4), lambda i: (i, 0, 0))
-        for _ in range(d)] + [pl.BlockSpec((bk, P2.WIDTH), lambda i: (i, 0))]
-    out_shape = [jax.ShapeDtypeStruct((K, d + 1, 4), jnp.uint32)] + [
-        jax.ShapeDtypeStruct((K, half, 4), jnp.uint32)
-        for _ in range(d)] + [jax.ShapeDtypeStruct((K, P2.WIDTH), jnp.uint32)]
-    outs = pl.pallas_call(
-        functools.partial(_round_kernel, d=d, unroll=unroll),
-        grid=(K // bk,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        interpret=not unroll,
-    )(states, rcf, rcp, diag, *vals)
-    g = outs[0]
-    folded = tuple(outs[1:d + 1])
-    new_states = outs[d + 1]
-    return g, folded, new_states, new_states[:, :4]
+
+TILE_CHUNK = 1 << 16    # elements relaid out per step of to_tiles
 
 
 @jax.jit
-def _epilogue(gs, cs, factors, states):
+def to_tiles(f: jnp.ndarray) -> jnp.ndarray:
+    """(K, n, 4) or (n, 4) factor -> the kernels' (K, 4, max(n/128, 1),
+    128) layout.
+
+    The relayout runs over chunks of ``TILE_CHUNK`` elements, each written
+    in place into the output: the TPU compiler takes time linear in n for
+    one whole-array relayout of an (n, 4) array (150 s at n = 2^25), and
+    the chunk loop needs no temporary the size of the factor."""
+    f = f.reshape(-1, f.shape[-2], 4)
+    K, n, _ = f.shape
+    if n < LANES:
+        f = jnp.pad(f, ((0, 0), (0, LANES - n), (0, 0)))
+        n = LANES
+    c = min(n, TILE_CHUNK)
+
+    def put(j, out):
+        x = jax.lax.dynamic_slice(f, (0, j * c, 0), (K, c, 4))
+        x = x.reshape(K, c // LANES, LANES, 4).transpose(0, 3, 1, 2)
+        return jax.lax.dynamic_update_slice(
+            out, x, (0, 0, j * (c // LANES), 0))
+
+    return jax.lax.fori_loop(
+        0, n // c, put, jnp.zeros((K, 4, n // LANES, LANES), jnp.uint32))
+
+
+def _per_claim(*shape) -> pl.BlockSpec:
+    return pl.BlockSpec((1,) + shape,
+                        lambda k, *_: (k,) + (0,) * len(shape))
+
+
+def _splats(K: int, *lead) -> jax.ShapeDtypeStruct:
+    return jax.ShapeDtypeStruct((K,) + lead + (1, LANES), jnp.uint32)
+
+
+_SEMANTICS = pltpu.CompilerParams(dimension_semantics=("parallel",
+                                                       "arbitrary"))
+
+
+def _tiling(tiles, n: int):
+    """Views, grid, factor spec and folded (rows, spec) for length n."""
+    K = tiles[0].shape[0]
+    if n <= LANES:
+        spec = pl.BlockSpec((1, 4, 1, LANES), lambda k, j: (k, 0, 0, 0))
+        return list(tiles), (K, 1), spec, 1, spec
+    half_rows = n // (2 * LANES)
+    blk = min(half_rows, ROW_BLOCK)
+    views = [t.reshape(K, 4, 2, half_rows, LANES) for t in tiles]
+    return (views, (K, half_rows // blk),
+            pl.BlockSpec((1, 4, 2, blk, LANES), lambda k, j: (k, 0, 0, j, 0)),
+            half_rows,
+            pl.BlockSpec((1, 4, blk, LANES), lambda k, j: (k, 0, j, 0)))
+
+
+@functools.partial(jax.jit, static_argnames=("n", "interpret"))
+def _eval_round(tiles, n: int, interpret: bool):
+    """g(0..d) of every claim, (K, d+1, 4, 1, 128) splats.  tiles: d
+    factors (K, 4, R, 128) of current length n; jitted per (K, n, d)."""
+    d = len(tiles)
+    views, grid, f_spec, _, _ = _tiling(tiles, n)
+    return pl.pallas_call(
+        functools.partial(_eval_kernel, d=d, n=n),
+        grid=grid,
+        in_specs=[f_spec] * d,
+        out_specs=_per_claim(d + 1, 4, 1, LANES),
+        out_shape=_splats(grid[0], d + 1, 4),
+        scratch_shapes=[pltpu.VMEM((d + 1, 4, 1, LANES), jnp.uint32)],
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+    )(*views)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _transcript_round(g, states, interpret: bool):
+    """Absorb g and squeeze the challenge: (states, c (K, 4, 1, 128)).
+    Jitted per (K, d) only, so its permutation compiles once per degree
+    and not once per factor length."""
+    K, dp1 = g.shape[:2]
+    per = P2.RATE // 4
+    rows = -(-dp1 // per) * per
+    g = jnp.pad(g, ((0, 0), (0, rows - dp1), (0, 0), (0, 0), (0, 0)))
+    return pl.pallas_call(
+        functools.partial(_transcript_kernel, d=dp1 - 1),
+        grid=(K,),
+        in_specs=[PK.rc_spec(), _per_claim(P2.WIDTH, 1, LANES),
+                  _per_claim(rows, 4, 1, LANES)],
+        out_specs=[_per_claim(P2.WIDTH, 1, LANES), _per_claim(4, 1, LANES)],
+        out_shape=[_splats(K, P2.WIDTH), _splats(K, 4)],
+        interpret=interpret,
+    )(PK.ROUND_CONSTANTS, states, g)
+
+
+@functools.partial(jax.jit, static_argnames=("n", "interpret"))
+def _fold_round(tiles, c, n: int, interpret: bool):
+    """Bind the leading variable of every factor at c: length n -> n/2."""
+    d = len(tiles)
+    views, grid, f_spec, out_rows, o_spec = _tiling(tiles, n)
+    return tuple(pl.pallas_call(
+        functools.partial(_fold_kernel, d=d, n=n),
+        grid=grid,
+        in_specs=[_per_claim(4, 1, LANES)] + [f_spec] * d,
+        out_specs=[o_spec] * d,
+        out_shape=[jax.ShapeDtypeStruct((grid[0], 4, out_rows, LANES),
+                                        jnp.uint32) for _ in range(d)],
+        compiler_params=_SEMANTICS,
+        interpret=interpret,
+    )(c, *views))
+
+
+@jax.jit
+def _ref_round(factors, states):
+    """The same round on the (K, n, 4) layout: the reference prover's round,
+    transcript and fold code vmapped over claims.  This is the CPU
+    execution of the kernel path; it stands in for the claim batching
+    only, and the kernel bodies are tested through ``force_pallas``."""
+    d = len(factors)
+    g, los, diffs = jax.vmap(SC._round_kernel)(factors)   # g: (K, d+1, 4)
+    states = jax.vmap(lambda s, e: T._absorb_any(s, e, 4 * (d + 1)))(
+        states, g)
+    states, c = jax.vmap(lambda s: T._squeeze_impl(s, 4))(states)
+    return g, jax.vmap(SC._fold_kernel)(los, diffs, c), states, c
+
+
+@jax.jit
+def _epilogue(gs, cs, finals, states):
     """Stack the per-round outputs and absorb the final evals, exactly as
     the reference prover's epilogue does.  Jitted per (K, d, m)."""
-    d = len(factors)
-    finals = jnp.stack([f[:, 0] for f in factors], axis=1)   # (K, d, 4)
+    d = finals.shape[1]
     states = jax.vmap(
         lambda s, e: T._absorb_any(s, e, 4 * d))(states, finals)
     return jnp.stack(gs, axis=1), jnp.stack(cs, axis=1), finals, states
 
 
-def _prove_rounds_impl(factors, states, pallas: bool, unroll: bool):
+def _prove_rounds_impl(factors, states, pallas: bool, interpret: bool):
     # A python loop of per-round jitted launches, NOT one enclosing jit:
-    # the per-round units are cached by (K, half, d) and shared across all
+    # the per-round units are cached by (K, n, d) and shared across all
     # sum-checks in a proof (an enclosing jit would recompile the whole
-    # m-round graph per distinct n — tens of seconds per shape on CPU).
-    # Warm per-round dispatch is microseconds; nothing syncs to host
-    # mid-prove, and no eager ops run between launches.
+    # m-round graph per distinct n).  Nothing syncs to host mid-prove.
     n = factors[0].shape[-2]
     m = n.bit_length() - 1
     gs, cs = [], []
-    for _ in range(m):
-        g, factors, states, c = _launch_round(factors, states,
-                                              pallas=pallas, unroll=unroll)
-        gs.append(g)
-        cs.append(c)                       # the challenge the kernel folded by
-    return _epilogue(tuple(gs), tuple(cs), factors, states)
+    if pallas:
+        # to_tiles takes (n, 4) factors as they come: an eager reshape
+        # would copy each factor once more on the device
+        tiles = tuple(to_tiles(f) for f in factors)
+        K = states.shape[0]
+        st = jnp.broadcast_to(states[:, :, None, None],
+                              (K, P2.WIDTH, 1, LANES))
+        for r in range(m):
+            g = _eval_round(tiles, n >> r, interpret)
+            st, c = _transcript_round(g, st, interpret)
+            tiles = _fold_round(tiles, c, n >> r, interpret)
+            gs.append(g[..., 0, 0])
+            cs.append(c[:, :, 0, 0])
+        finals = jnp.stack([t[:, :, 0, 0] for t in tiles], axis=1)
+        states = st[:, :, 0, 0]
+    else:
+        factors = tuple(f.reshape(-1, n, 4) for f in factors)
+        for _ in range(m):
+            g, factors, states, c = _ref_round(factors, states)
+            gs.append(g)
+            cs.append(c)
+        finals = jnp.stack([f[:, 0] for f in factors], axis=1)
+    return _epilogue(tuple(gs), tuple(cs), finals, states)
 
 
 def prove_rounds(factors: Sequence[jnp.ndarray], states: jnp.ndarray,
                  interpret: bool = True, force_pallas: bool = False
                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Run the full sum-check prover for K batched claims, one fused launch
-    per round index, all m rounds under a single jit.
+    """Run the full sum-check prover for K batched claims.
 
     factors: d arrays of shape (K, n, 4) — claim k's factor t is
     ``factors[t][k]``; n must be a power of two >= 2.  states: (K, 16)
     sponge states, one transcript per claim.  Single-claim callers may
     pass (n, 4) factors with a (16,) state and read row 0 of each output.
 
-    On TPU (``interpret=False``) each round is one compiled Pallas launch.
-    On CPU the identical kernel body executes directly under the jit
-    (interpret-mode pallas_call tracing costs ~5 s per launch, which would
-    dominate CI; ``force_pallas=True`` drives the real pallas_call in
-    interpret mode anyway — used by the differential tests).
+    On TPU (``interpret=False``) each round is three compiled Pallas
+    launches.  On CPU the same round runs as plain jnp under one jit per
+    round (interpret-mode pallas_call costs seconds per launch, which
+    would dominate the CPU runs); ``force_pallas=True`` drives the real
+    pallas_call in interpret mode anyway — used by the differential tests.
 
     Returns ``(round_polys (K, m, d+1, 4), points (K, m, 4),
     final_evals (K, d, 4), new_states (K, 16))`` — exactly the data the
     reference prover would have produced claim-by-claim, with transcripts
     advanced identically.
     """
-    factors = tuple(jnp.asarray(f) for f in factors)
-    shape = factors[0].shape
+    shape = jnp.shape(factors[0])
     n = shape[-2]
-    assert all(f.shape == shape for f in factors) and shape[-1] == 4
+    assert all(jnp.shape(f) == shape for f in factors) and shape[-1] == 4
     assert n >= 2 and n & (n - 1) == 0
-    return _prove_rounds_impl(factors, jnp.asarray(states),
+    factors = tuple(jnp.asarray(f) for f in factors)
+    states = jnp.asarray(states).reshape(-1, P2.WIDTH)
+    return _prove_rounds_impl(factors, states,
                               pallas=force_pallas or not interpret,
-                              unroll=not interpret)
+                              interpret=interpret)

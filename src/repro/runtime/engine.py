@@ -298,6 +298,13 @@ class ProverEngine:
                  backend: str = "thread"):
         assert len(cfgs) == len(weights_raw)
         assert backend in ("thread", "process")
+        if backend == "process" and KOPS.on_tpu():
+            # this process holds the chip; a spawned worker would fail or
+            # hang trying to open it
+            raise RuntimeError(
+                "ProverEngine(backend='process') cannot run on a TPU: the "
+                "chip belongs to this process, so worker processes cannot "
+                "reach it. Use backend='thread'.")
         self.cfgs = list(cfgs)
         self.weights_raw = list(weights_raw)
         self.params = params

@@ -121,6 +121,21 @@ def test_process_backend_matches_sequential(engine_setup):
     assert report.jobs == L
 
 
+def test_process_backend_refused_on_tpu(monkeypatch):
+    """A chip belongs to one process: on a TPU the process backend raises
+    before any worker is spawned."""
+    import multiprocessing
+
+    from repro.kernels import ops as KOPS
+    monkeypatch.setattr(KOPS, "on_tpu", lambda: True)
+    rng = np.random.default_rng(0)
+    with pytest.raises(RuntimeError, match="cannot run on a TPU"):
+        ProverEngine([CFG], [B.init_weights(CFG, rng)],
+                     PCS.PCSParams(blowup=4, queries=2), workers=2,
+                     backend="process")
+    assert not multiprocessing.active_children()
+
+
 def test_weight_cache_hit_miss(engine_setup):
     params, weights, x0, cache, eng, _, _ = engine_setup
     # the fixture's setup was the miss path: one range proof per layer

@@ -1,6 +1,5 @@
 """Field arithmetic: exactness vs Python-int ground truth + ring axioms."""
 import numpy as np
-import jax.numpy as jnp
 from hypothesis_compat import given, settings, st
 
 from repro.core import field as F
@@ -9,7 +8,7 @@ fp_elem = st.integers(min_value=0, max_value=F.P - 1)
 
 
 def _mont(xs):
-    return F.to_mont(jnp.asarray(np.asarray(xs, dtype=np.uint32)))
+    return F.f_from_int(xs)
 
 
 @given(st.lists(fp_elem, min_size=1, max_size=64), st.lists(fp_elem, min_size=1, max_size=64))

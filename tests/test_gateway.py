@@ -16,6 +16,7 @@ Crypto-bearing fixtures are module-scoped (one service, serial twins
 proven once) to keep the proving budget bounded.
 """
 import contextlib
+import importlib.util
 import json
 import os
 import threading
@@ -472,3 +473,23 @@ def test_streaming_default_caps_accept_normal_stream(service, queries,
         for rep in sv.feed(wire[off:off + 1024]):
             assert rep.ok, rep.reason
     assert sv.finish().ok
+
+
+def test_chip_smoke_phases_at_toy_width(monkeypatch):
+    """CPU rehearsal of ``chip_smoke.py``: its phases (gateway serving with
+    stream verification, alongside it the kernel-path attestation, then
+    the ``ref`` byte-identity check) at this file's toy width, on the
+    kernel path a TPU defaults to; only the TPU device check is skipped."""
+    path = os.path.join(os.path.dirname(__file__), "..", "chip_smoke.py")
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    monkeypatch.setenv("NANOZK_KERNEL_PATH", "fused")
+    lines = []
+    out = smoke.run_phases(CFG, CFG, n_queries=2, pcs_queries=QUERIES,
+                           log=lines.append)
+    assert out["serve"] == {"kernel_path": "fused", "verified": 2}
+    assert out["oracle"]["kernel_path"] == "fused"
+    assert out["oracle"]["identical_bytes"] > 0
+    assert any("byte-identical" in line for line in lines)
+    assert os.environ["NANOZK_KERNEL_PATH"] == "fused"

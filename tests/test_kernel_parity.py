@@ -9,14 +9,15 @@ Property-based (hypothesis, degrading to skips when absent — see
 hypothesis_compat) with deterministic rng-driven twins so every kernel is
 exercised either way.  Element strategies mix uniform field elements with
 the carry-saturating edges (0, 1, p-1, p-2, 2^31-1 mod p) that stress the
-Montgomery reduction paths.  ``force_pallas=True`` variants drive the real
-``pallas_call`` wiring in interpret mode on small shapes (the CPU prover
-otherwise runs the identical math directly under jit — see
-kernels/sumcheck_round.py).
+Montgomery reduction paths.  The kernel cases pass ``force_pallas=True``,
+which drives the real ``pallas_call`` bodies in interpret mode on small
+shapes: the CPU prover otherwise runs the reference math under jit (see
+kernels/sumcheck_round.py), which would compare the oracle with itself.
 """
 import contextlib
 import os
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -101,43 +102,50 @@ def _check_prove_rounds(factors, state, **kw):
     _eq(np.asarray(states)[0], st_ref)
 
 
-@given(st.lists(felt, min_size=16 * 4 * 2, max_size=16 * 4 * 2),
+@needs_pallas
+@given(st.lists(felt, min_size=8 * 4 * 2, max_size=8 * 4 * 2),
        st.integers(min_value=1, max_value=3),
        st.lists(felt, min_size=16, max_size=16))
 @settings(max_examples=15, deadline=None)
 def test_fused_round_prover_matches_reference(vals, d, seed_state):
     """Full fused prover (all rounds: evals, absorb, challenge, fold) is
     transcript-identical to the reference loop, for 1..3 factors."""
-    n = 16
+    n = 8
     factors = tuple(_f4(vals[t * n * 4:(t + 1) * n * 4], n)
                     for t in range(d)) if d <= 2 else tuple(
         _f4(vals[:n * 4], n) for _ in range(d))
     state = _mont(seed_state, (16,))
-    _check_prove_rounds(factors, state)
+    _check_prove_rounds(factors, state, force_pallas=True)
 
 
+@needs_pallas
 def test_fused_round_prover_edge_values(rng):
-    """Deterministic twin: uniform + all-zero + all-(p-1) factors."""
-    n = 32
+    """Deterministic twin: uniform + all-zero + all-(p-1) factors (n as
+    above, so the kernels compiled there are reused)."""
+    n = 8
     state = F.f_from_int(rng.integers(0, P, (16,)))
     for d in (1, 2, 3):
         factors = tuple(
             F.f4_from_base(F.f_from_int(rng.integers(0, P, n)))
             for _ in range(d))
-        _check_prove_rounds(factors, state)
+        _check_prove_rounds(factors, state, force_pallas=True)
     zeros = np.zeros((n, 4), np.uint32)
     tops = np.asarray(_f4([P - 1] * n * 4, n))
-    _check_prove_rounds((zeros, tops), state)
+    _check_prove_rounds((zeros, tops), state, force_pallas=True)
 
 
-def test_fused_round_prover_batched_claims(rng):
+@pytest.mark.parametrize("force_pallas", [False, pytest.param(
+    True, marks=needs_pallas)])
+def test_fused_round_prover_batched_claims(rng, force_pallas):
     """K stacked claims reproduce K independent single-claim transcripts —
-    the property the engine's SumcheckRoundBatcher relies on."""
-    n, d, K = 16, 2, 3
+    the property the engine's SumcheckRoundBatcher relies on, on the
+    kernels' per-claim grid and on the CPU's vmapped rounds."""
+    n, d, K = 8, 3, 3
     factors = [F.f_from_int(rng.integers(0, P, (K, n, 4)))
                for _ in range(d)]
     states = F.f_from_int(rng.integers(0, P, (K, 16)))
-    rp, pts, finals, sts = SR.prove_rounds(tuple(factors), states)
+    rp, pts, finals, sts = SR.prove_rounds(tuple(factors), states,
+                                           force_pallas=force_pallas)
     for k in range(K):
         fk = tuple(f[k] for f in factors)
         proof, point, st_ref = _reference_prove(fk, states[k])
@@ -150,12 +158,39 @@ def test_fused_round_prover_batched_claims(rng):
 @needs_pallas
 def test_fused_round_prover_force_pallas(rng):
     """The real pallas_call wiring (interpret mode) matches the reference
-    prover bit-for-bit on a small shape."""
+    prover bit-for-bit over every round of a lane-split (n <= 128)
+    sum-check."""
     n = 8
     factors = tuple(F.f_from_int(rng.integers(0, P, (n, 4)))
                     for _ in range(2))
     state = F.f_from_int(rng.integers(0, P, (16,)))
     _check_prove_rounds(factors, state, force_pallas=True)
+
+
+@needs_pallas
+def test_round_kernels_row_tiles_force_pallas(rng, monkeypatch):
+    """One row-split round (n > 128) streamed over several grid tiles (one
+    row per tile) through the real pallas_call wiring, for one claim and
+    for three on the per-claim grid: g, transcript, challenge and folded
+    factors equal the reference round, exactly."""
+    monkeypatch.setattr(SR, "ROW_BLOCK", 1)
+    n, d = 512, 3
+    for K in (1, 3):
+        factors = tuple(F.f_from_int(rng.integers(0, P, (K, n, 4)))
+                        for _ in range(d))
+        states = F.f_from_int(rng.integers(0, P, (K, 16)))
+        g_r, folded_r, st_r, c_r = SR._ref_round(factors, states)
+        tiles = tuple(SR.to_tiles(f) for f in factors)
+        g = SR._eval_round(tiles, n, interpret=True)
+        st, c = SR._transcript_round(
+            g, jnp.broadcast_to(states[:, :, None, None], (K, 16, 1, 128)),
+            interpret=True)
+        folded = SR._fold_round(tiles, c, n, interpret=True)
+        _eq(g[..., 0, 0], g_r)
+        _eq(st[:, :, 0, 0], st_r)
+        _eq(c[:, :, 0, 0], c_r)
+        for t, f in zip(folded, folded_r):
+            _eq(t, SR.to_tiles(f))
 
 
 # ---------------------------------------------------------------------------
@@ -197,49 +232,57 @@ def test_fold_round_block_reduction(rng):
 # ---------------------------------------------------------------------------
 # Poseidon2: permutation, Merkle compression, sponge hashing.
 # ---------------------------------------------------------------------------
+def _felts(rng, shape, inputs):
+    """Montgomery field elements: uniform, or drawn from the edges."""
+    if inputs == "edges":
+        return _mont(rng.choice(EDGES, shape), shape)
+    return F.f_from_int(rng.integers(0, P, shape))
+
+
 @given(st.lists(felt, min_size=4 * 16, max_size=4 * 16))
 @settings(max_examples=15, deadline=None)
 def test_poseidon2_permute_property(vals):
     states = _mont(vals, (4, 16))
-    _eq(ops.poseidon2_permute(states, block=4), P2.permute(states))
+    _eq(ops.poseidon2_permute(states), P2.permute(states))
 
 
-@pytest.mark.parametrize("force_pallas", [False, pytest.param(
-    True, marks=needs_pallas)])
-def test_poseidon2_compress_pairs(rng, force_pallas):
-    left = F.f_from_int(rng.integers(0, P, (6, P2.DIGEST)))
-    right = F.f_from_int(rng.integers(0, P, (6, P2.DIGEST)))
-    got = PK.compress_pairs(left, right, block=4,
-                            force_pallas=force_pallas)
+@needs_pallas
+@pytest.mark.parametrize("inputs", ["uniform", "edges"])
+def test_poseidon2_compress_pairs(rng, inputs):
+    left = _felts(rng, (6, P2.DIGEST), inputs)
+    right = _felts(rng, (6, P2.DIGEST), inputs)
+    got = PK.compress_pairs(left, right, force_pallas=True)
     _eq(got, P2.compress(left, right))
 
 
+@needs_pallas
 @pytest.mark.parametrize("n_elems", [1, 7, 8, 9, 24])
-@pytest.mark.parametrize("force_pallas", [False, pytest.param(
-    True, marks=needs_pallas)])
-def test_poseidon2_hash_rows(rng, n_elems, force_pallas):
+@pytest.mark.parametrize("inputs", ["uniform", "edges"])
+def test_poseidon2_hash_rows(rng, n_elems, inputs):
     """Sponge schedule (length tag, RATE chunking, padding) matches
     hash_elems for lengths below/at/above one RATE chunk."""
-    elems = F.f_from_int(rng.integers(0, P, (5, n_elems)))
-    got = PK.hash_rows(elems, block=4, force_pallas=force_pallas)
+    elems = _felts(rng, (5, n_elems), inputs)
+    got = PK.hash_rows(elems, force_pallas=True)
     _eq(got, P2.hash_elems(elems))
 
 
+@needs_pallas
 def test_poseidon2_hash_edge_values():
-    for v in (0, P - 1):
-        elems = np.full((2, 11), v, np.uint32)
-        _eq(PK.hash_rows(elems), P2.hash_elems(elems))
+    for v in (0, P - 1):                 # a shape hash_rows compiles
+        elems = np.full((5, 9), v, np.uint32)
+        _eq(PK.hash_rows(elems, force_pallas=True), P2.hash_elems(elems))
 
 
 # ---------------------------------------------------------------------------
 # NTT (Reed-Solomon encoding path).
 # ---------------------------------------------------------------------------
+@needs_pallas
 @given(st.lists(felt, min_size=2 * 32, max_size=2 * 32),
        st.booleans())
 @settings(max_examples=15, deadline=None)
 def test_ntt_rows_property(vals, inverse):
     x = _mont(vals, (2, 32))
-    _eq(ops.ntt(x, inverse=inverse, block=2),
+    _eq(NK.ntt_rows(x, inverse=inverse, force_pallas=True),
         NTT.ntt(x, inverse=inverse))
 
 
@@ -247,12 +290,12 @@ def test_ntt_rows_property(vals, inverse):
 def test_ntt_rows_force_pallas(rng):
     x = F.f_from_int(rng.integers(0, P, (4, 16)))
     for inverse in (False, True):
-        _eq(NK.ntt_rows(x, inverse=inverse, block=2, force_pallas=True),
+        _eq(NK.ntt_rows(x, inverse=inverse, force_pallas=True),
             NTT.ntt(x, inverse=inverse))
     # edge rows: all-zero and all-(p-1)
     edges = np.stack([np.zeros(16, np.uint32),
                       np.asarray(_mont([P - 1] * 16, (16,)))])
-    _eq(NK.ntt_rows(edges, block=2, force_pallas=True), NTT.ntt(edges))
+    _eq(NK.ntt_rows(edges, force_pallas=True), NTT.ntt(edges))
 
 
 # ---------------------------------------------------------------------------

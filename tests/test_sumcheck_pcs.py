@@ -66,6 +66,35 @@ def test_pcs_roundtrip_and_tamper(rng, params):
                                    params)
 
 
+@pytest.mark.parametrize("chunk_log", [16, 2])
+def test_build_e_vec_matches_naive_fold(rng, monkeypatch, chunk_log):
+    """The slice-wise e_vec equals sum_i gamma^i eq(p_i, .) exactly, for
+    const-prefixed (slice) and full points, with groups written whole
+    (chunk_log 16) or in 2^2-long chunks."""
+    from repro.core.mle import eq_points
+    monkeypatch.setattr(PCS, "_E_CHUNK_LOG", chunk_log)
+    PCS._bucket_e_impl.clear_cache()
+    m = 7
+    one, zero = F.f_from_int([1, 0, 0, 0]), F.f_from_int([0, 0, 0, 0])
+
+    def point(prefix):
+        rnd = F.f_from_int(rng.integers(0, F.P, (m - len(prefix), 4)))
+        bits = [one if b else zero for b in prefix]
+        return np.asarray(jnp.concatenate([jnp.stack(bits), rnd])
+                          if bits else rnd)
+
+    pts = [point(p) for p in ((), (1,), (1,), (0, 1), (1, 1, 0), ())]
+    gamma = F.f_from_int(rng.integers(0, F.P, 4))
+    want, w = jnp.zeros((1 << m, 4), jnp.uint32), F.f4one(())
+    for p in pts:
+        want = F.f4add(want, F.f4mul(jnp.broadcast_to(w, want.shape),
+                                     eq_points(jnp.asarray(p))))
+        w = F.f4mul(w, gamma)
+    got = PCS._build_e_vec(1 << m, pts, gamma)
+    PCS._bucket_e_impl.clear_cache()
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_matmul_claims_match_direct_mle(rng):
     n, k, m = 8, 16, 4
     A = rng.integers(-50, 50, (n, k))
