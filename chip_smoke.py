@@ -8,10 +8,11 @@ localhost socket, and a ``GatewayClient`` that stream-verifies every
 attestation it receives.  The block is GPT-2 small at its published
 widths (d=768, dff=3072, 12 heads of 64; ``configs/gpt2_small.py``), one
 layer deep, seq 8 (as ``benchmarks/table3_block_proof.py``), with random
-quantized weights from a fixed seed.  An oracle check attests one query
-at the paper's d=128 under the Pallas kernel path (alongside the
-serving) and then under the jnp reference path, and requires identical
-wire bytes: the oracle contract, checked on the chip's own compiler.
+quantized weights from a fixed seed.  The client verifies on the host
+CPU, as clients do.  Alongside the serving, an oracle check attests one
+query at the paper's d=128 under the Pallas kernel path and, in another
+thread, under the jnp reference path, and requires identical wire
+bytes: the oracle contract, checked on the chip's own compiler.
 
 Exits non-zero and prints no result when JAX finds no TPU, or when any
 phase fails.  The lines before the last are smoke timings on the device
@@ -43,17 +44,14 @@ def gpt2_block(d: int, heads: int, dh: int, dff: int, seq: int = 8):
 
 
 @contextlib.contextmanager
-def kernel_path(path: str):
-    """Select the prover's kernel path for the block (NANOZK_KERNEL_PATH)."""
-    old = os.environ.get("NANOZK_KERNEL_PATH")
-    os.environ["NANOZK_KERNEL_PATH"] = path
-    try:
+def on_host():
+    """Run the block's JAX work on the host CPU, where clients verify.
+    No kernel runs there, so the client's own commitment to its query
+    takes the reference path."""
+    import jax
+    from repro.kernels import ops as KOPS
+    with jax.default_device(jax.devices("cpu")[0]), KOPS.thread_path("ref"):
         yield
-    finally:
-        if old is None:
-            os.environ.pop("NANOZK_KERNEL_PATH", None)
-        else:
-            os.environ["NANOZK_KERNEL_PATH"] = old
 
 
 def _query(cfg, rng) -> np.ndarray:
@@ -87,7 +85,8 @@ def serve_phase(cfg, n_queries: int, pcs_queries: int, log) -> dict:
             for i, q in enumerate(queries):
                 t = time.monotonic()
                 with GatewayClient(host, port, client_id=f"smoke-{i}",
-                                   timeout=CLIENT_TIMEOUT) as client:
+                                   timeout=CLIENT_TIMEOUT) as client, \
+                        on_host():
                     rep = client.attest_verify(q, card, policy)
                 wall = time.monotonic() - t
                 if not rep.ok:
@@ -105,24 +104,28 @@ def serve_phase(cfg, n_queries: int, pcs_queries: int, log) -> dict:
     return {"kernel_path": path, "verified": n_queries}
 
 
-def oracle_attestation(cfg, weights, query, pcs_queries: int, log) -> tuple:
+def oracle_attestation(cfg, weights, query, pcs_queries: int, log,
+                       path=None) -> tuple:
     """(kernel path, wire bytes, model card bytes) of one verified
-    attestation of ``query`` on the process's current kernel path.  Each call runs its own weight
+    attestation of ``query``, proven in the calling thread on ``path``
+    (the platform default when None).  Each call runs its own weight
     setup (commitment and range proof), so the roots the attestation
     carries are computed on that path too."""
     from repro import api
     from repro.kernels import ops as KOPS
 
-    path = KOPS.kernel_path()
-    policy = api.VerifyPolicy(pcs_queries=pcs_queries)
-    t0 = time.monotonic()
-    with api.ProofService([cfg], weights, default_queries=pcs_queries,
-                          workers=1, name="chip-smoke-oracle") as svc:
-        att = svc.attest(query, policy)
-        card = svc.model_card
+    with (KOPS.thread_path(path) if path else contextlib.nullcontext()):
+        path = KOPS.kernel_path()
+        policy = api.VerifyPolicy(pcs_queries=pcs_queries)
+        t0 = time.monotonic()
+        with api.ProofService([cfg], weights, default_queries=pcs_queries,
+                              workers=1, name="chip-smoke-oracle") as svc:
+            att = svc.attest(query, policy)
+            card = svc.model_card
     att.prove_seconds = 0.0                # wall-clock telemetry
     wire = att.to_bytes(2)
-    rep = api.verify(wire, query, card, policy)
+    with on_host():
+        rep = api.verify(wire, query, card, policy)
     if not rep.ok:
         raise AssertionError(
             f"d={cfg.d} {path} attestation rejected: {rep.reason}")
@@ -135,26 +138,26 @@ def run_phases(serve_cfg, oracle_cfg, n_queries: int = N_QUERIES,
                pcs_queries: int = PCS_QUERIES, log=print) -> dict:
     """Every phase of the smoke run; raises on the first failure.
 
-    The oracle check attests one ``oracle_cfg`` query on the current
+    The oracle check attests one ``oracle_cfg`` query on the platform's
     kernel path and again on ``ref``; the two must verify and be
     byte-identical on the wire, and so must their model cards (each
-    path runs its own weight setup).  Its first attestation runs in a second
-    thread while the gateway serves: a cold run is mostly compilation,
-    which releases the GIL, so the two phases' compiles overlap.  The
-    ``ref`` twin runs after both, since the path is chosen process-wide."""
+    path runs its own weight setup).  Both attestations run in threads
+    of their own while the gateway serves: a cold run is mostly
+    compilation, which releases the GIL, so the three provers' compiles
+    overlap."""
     from repro.core import blocks as B
 
     rng = np.random.default_rng(SEED + 1)
     weights = [B.init_weights(oracle_cfg, rng)]
     query = _query(oracle_cfg, rng)
-    with concurrent.futures.ThreadPoolExecutor(1) as pool:
+    with concurrent.futures.ThreadPoolExecutor(2) as pool:
         first = pool.submit(oracle_attestation, oracle_cfg, weights, query,
                             pcs_queries, log)
+        twin = pool.submit(oracle_attestation, oracle_cfg, weights, query,
+                           pcs_queries, log, "ref")
         serve = serve_phase(serve_cfg, n_queries, pcs_queries, log)
         path, wire, card = first.result()
-    with kernel_path("ref"):
-        _, ref_wire, ref_card = oracle_attestation(oracle_cfg, weights,
-                                                   query, pcs_queries, log)
+        _, ref_wire, ref_card = twin.result()
     if card != ref_card:
         raise AssertionError(f"d={oracle_cfg.d}: the {path!r} path's weight "
                              "commitment differs from the reference path's")

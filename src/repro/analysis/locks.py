@@ -60,6 +60,7 @@ LOCK_RANKS: Dict[Tuple[str, Optional[str], str], int] = {
     ("gateway/admission.py", "AdmissionQueue", "_cv"): 70,
     ("runtime/fault.py", "ProofWorkReplayQueue", "_lock"): 70,
     ("analysis/replay.py", "ReplayLog", "_mu"): 70,
+    ("kernels/ahead.py", None, "_LOCK"): 70,
 }
 
 # Modules that own a ranked lock must carry a "Lock order" docstring

@@ -688,7 +688,7 @@ def _covered_ops_entry_points() -> List[str]:
     """Public kernel entry wrappers in ops.py that must appear in the
     registry — coverage is asserted, not assumed."""
     import inspect
-    skip = {"kernel_path", "use_fused", "on_tpu"}
+    skip = {"kernel_path", "thread_path", "use_fused", "on_tpu"}
     out = []
     for nm, obj in vars(KOPS).items():
         if (not nm.startswith("_") and nm not in skip

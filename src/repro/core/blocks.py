@@ -228,28 +228,35 @@ def block_forward(cfg: BlockCfg, w: Dict[str, np.ndarray], x: np.ndarray
     else:
         q_att, k_att = q, k
 
-    # attention heads
+    # attention heads; the softmax witness spans a power-of-two number of
+    # heads, and a padding head (all-zero q, k, v) gets the witness its
+    # zero scores give, so the relations batched over heads hold on it
     mask = cfg.causal_mask
     group = H // KV
-    sidx = np.zeros((H, seq, seq), dtype=np.int64)
+    Hs = _pad2(H)
+    sidx = np.zeros((Hs, seq, seq), dtype=np.int64)
     err_s = np.zeros_like(sidx)
     e_arr = np.zeros_like(sidx)
     P_arr = np.zeros_like(sidx)
     w1_arr = np.zeros_like(sidx)
     w2_arr = np.zeros_like(sidx)
-    S_arr = np.zeros((H, seq), dtype=np.int64)
+    S_arr = np.zeros((Hs, seq), dtype=np.int64)
     O = np.zeros((qd, seq), dtype=np.int64)
     err_o = np.zeros((qd, seq), dtype=np.int64)
-    for h in range(H):
+    zero = np.zeros((dh, seq), dtype=np.int64)
+    for h in range(Hs):
         kvh = h // group
-        th = Q.q_attention_head(q_att[h * dh:(h + 1) * dh],
-                                k_att[kvh * dh:(kvh + 1) * dh],
-                                v[kvh * dh:(kvh + 1) * dh], mask)
+        th = Q.q_attention_head(q_att[h * dh:(h + 1) * dh] if h < H else zero,
+                                k_att[kvh * dh:(kvh + 1) * dh] if h < H
+                                else zero,
+                                v[kvh * dh:(kvh + 1) * dh] if h < H else zero,
+                                mask)
         sidx[h], err_s[h], e_arr[h] = th["sidx"], th["err_s"], th["e"]
         P_arr[h], w1_arr[h], w2_arr[h] = th["P"], th["w1"], th["w2"]
         S_arr[h] = th["S"]
-        O[h * dh:(h + 1) * dh] = th["o"]
-        err_o[h * dh:(h + 1) * dh] = th["err_o"]
+        if h < H:
+            O[h * dh:(h + 1) * dh] = th["o"]
+            err_o[h * dh:(h + 1) * dh] = th["err_o"]
     tr.update(sidx=sidx, err_s=err_s, e=e_arr, P=P_arr, w1=w1_arr,
               w2=w2_arr, S=S_arr, O=O, err_o=err_o)
 
@@ -381,13 +388,14 @@ def declare_aux(cfg: BlockCfg, wb: C.WitnessBuilder,
         ranged("err_rq", qd * seq, Q.ROPE_F)
         limb("kr", kv * seq)
         ranged("err_rk", kv * seq, Q.ROPE_F)
-    limb("sidx", H * seq * seq)
-    ranged("err_s", H * seq * seq, 12)
-    ranged("e", H * seq * seq, lut_bits["exp"])
-    ranged("S", H * seq, bS)
-    ranged("P", H * seq * seq, 9)
-    ranged("w1", H * seq * seq, bS + 1)
-    ranged("w2", H * seq * seq, bS + 1)
+    Hs = _pad2(H)                 # softmax witness heads (see the trace)
+    limb("sidx", Hs * seq * seq)
+    ranged("err_s", Hs * seq * seq, 12)
+    ranged("e", Hs * seq * seq, lut_bits["exp"])
+    ranged("S", Hs * seq, bS)
+    ranged("P", Hs * seq * seq, 9)
+    ranged("w1", Hs * seq * seq, bS + 1)
+    ranged("w2", Hs * seq * seq, bS + 1)
     limb("O", qd * seq)
     ranged("err_o", qd * seq, 8)
     limb("proj", d * seq)
@@ -695,7 +703,7 @@ def block_argument(ctx, cfg: BlockCfg, V: Views, Vw: Views,
     C.g_lut(ctx, "exp", exp_idx, V.ranged("e"),
             tr_ints["exp_idx"] if tr_ints else None,
             tr_ints["exp_out"] if tr_ints else None,
-            H * seq * seq, "exp lut")
+            _pad2(H) * seq * seq, "exp lut")
     act = cfg.act
     act_idx = C.vaff([(1, V.limb("gidx"))], const=32768)
     C.g_lut(ctx, act, act_idx, V.limb("gout"),

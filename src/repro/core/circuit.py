@@ -31,6 +31,7 @@ from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from . import field as F
@@ -41,6 +42,9 @@ from . import sumcheck as SC
 from .mle import (eq_eval, eq_points, fsum, mle_eval_base,
                   partial_eval_cols, partial_eval_rows)
 from .transcript import Transcript
+
+from repro.kernels import ahead as AH
+from repro.kernels import ops as KOPS
 
 INV2 = (F.P + 1) // 2    # field inverse of 2 as a canonical int
 
@@ -957,9 +961,15 @@ def flush_lookups(ctx: Ctx, helper_name: str = "lkh", aspect: int = 0):
     a_vecs: List[Optional[jnp.ndarray]] = [None] * len(reqs)
     if ctx.is_prover:
         helper = np.zeros(total, dtype=np.uint32)
+        inv_ahead = {}
+        if KOPS.on_tpu():   # one inversion program per instance length
+            inv_ahead = {r.log_n: AH.start(F.f4inv, jax.ShapeDtypeStruct(
+                (1 << r.log_n, 4), jnp.uint32)) for r in reqs}
         for i, req in enumerate(reqs):
             w = _lookup_w_f4(ctx, req, betas[i])
             ab = jnp.broadcast_to(alpha, w.shape)
+            if inv_ahead:
+                inv_ahead[req.log_n].result()
             a = F.f4inv(F.f4sub(ab, w))                  # (n_i, 4)
             a_vecs[i] = a
             a_np = np.asarray(a)

@@ -10,11 +10,14 @@ import dataclasses
 from typing import List
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from . import poseidon2 as P2
 
+from repro.kernels import ahead as AH
 from repro.kernels import ops as KOPS
+from repro.kernels import poseidon2_kernel as PK
 
 
 def _hash_leaves(leaves: jnp.ndarray) -> jnp.ndarray:
@@ -30,6 +33,32 @@ def _compress_level(left: jnp.ndarray, right: jnp.ndarray) -> jnp.ndarray:
     if KOPS.use_fused():
         return KOPS.poseidon2_compress(left, right)
     return P2.compress(left, right)
+
+
+def prepare(shape) -> list:
+    """Start compiling, on a TPU's kernel path, the leaf hash and every
+    level's compression of a tree over leaves of ``shape`` ((n, leaf_len)
+    or (B, n, leaf_len)); their futures in the order ``commit`` runs
+    them, or none elsewhere."""
+    if not (KOPS.use_fused() and KOPS.on_tpu()):
+        return []
+    *batch, n, _ = shape
+
+    def u32(*s):
+        return jax.ShapeDtypeStruct((*batch, *s), jnp.uint32)
+
+    futs = [AH.start(PK.hash_rows, u32(*shape[-2:]), interpret=False)]
+    k = 1 << max((n - 1).bit_length(), 0) if n > 1 else 1
+    while k > 1:
+        k //= 2
+        futs.append(AH.start(PK.compress_pairs, u32(k, P2.DIGEST),
+                             u32(k, P2.DIGEST), interpret=False))
+    return futs
+
+
+def _wait(futs: list, i: int) -> None:
+    if futs:
+        futs[i].result()
 
 
 @dataclasses.dataclass
@@ -48,6 +77,8 @@ class MerkleTree:
 def commit(leaves: jnp.ndarray) -> MerkleTree:
     """leaves: (n, leaf_len) field elements; n padded to a power of two."""
     n = leaves.shape[0]
+    futs = prepare(leaves.shape)
+    _wait(futs, 0)
     digests = _hash_leaves(leaves)
     n_pad = 1 << max((n - 1).bit_length(), 0) if n > 1 else 1
     if n_pad != n:
@@ -56,6 +87,7 @@ def commit(leaves: jnp.ndarray) -> MerkleTree:
     levels = [digests]
     while levels[-1].shape[0] > 1:
         cur = levels[-1]
+        _wait(futs, len(levels))
         levels.append(_compress_level(cur[0::2], cur[1::2]))
     return MerkleTree(levels=levels)
 
@@ -70,6 +102,8 @@ def commit_batch(leaves: jnp.ndarray) -> List[MerkleTree]:
     bit-identical to ``commit(leaves[i])``.
     """
     b, n = leaves.shape[0], leaves.shape[1]
+    futs = prepare(leaves.shape)
+    _wait(futs, 0)
     digests = _hash_leaves(leaves)                        # (B, n, DIGEST)
     n_pad = 1 << max((n - 1).bit_length(), 0) if n > 1 else 1
     if n_pad != n:
@@ -79,6 +113,7 @@ def commit_batch(leaves: jnp.ndarray) -> List[MerkleTree]:
     levels = [digests]
     while levels[-1].shape[1] > 1:
         cur = levels[-1]
+        _wait(futs, len(levels))
         levels.append(_compress_level(cur[:, 0::2], cur[:, 1::2]))
     return [MerkleTree(levels=[lv[i] for lv in levels]) for i in range(b)]
 
@@ -114,7 +149,9 @@ def verify_path(root: np.ndarray, leaf: jnp.ndarray, path: MerklePath) -> bool:
 
 
 def batch_open(tree: MerkleTree, indices) -> List[MerklePath]:
-    return [open_path(tree, int(i)) for i in indices]
+    # one host copy per level, not one device gather per (index, level)
+    host = MerkleTree(levels=[np.asarray(lv) for lv in tree.levels])
+    return [open_path(host, int(i)) for i in indices]
 
 
 def root_from_path(leaf: jnp.ndarray, path: MerklePath) -> np.ndarray:

@@ -53,6 +53,7 @@ from . import transcript as T
 from .mle import eq_eval, eq_points, fsum, mle_eval_base, partial_eval_rows
 from .transcript import Transcript
 
+from repro.kernels import ahead as AH
 from repro.kernels import ops as KOPS
 
 
@@ -168,6 +169,8 @@ def commit(vec: jnp.ndarray, params: PCSParams, aspect: int = 0) -> Commitment:
     if total != n:
         vec = jnp.concatenate([vec, jnp.zeros((total - n,), jnp.uint32)])
     mat = vec.reshape(1 << log_r, 1 << log_c)
+    # the tree's programs compile while the rows encode
+    M.prepare(((1 << log_c) * params.blowup, 1 << log_r))
     enc = _rs_encode(mat, params.blowup)
     tree = M.commit(enc.T)                      # leaves are columns
     return Commitment(mat=mat, enc=enc, tree=tree, log_r=log_r, log_c=log_c)
@@ -193,6 +196,7 @@ def commit_batch(vecs: Sequence[jnp.ndarray], params: PCSParams
         (jnp.concatenate([v, jnp.zeros((total - n,), jnp.uint32)])
          if total != n else v).reshape(1 << log_r, 1 << log_c)
         for v in vecs])                                  # (B, R, C)
+    M.prepare((len(vecs), (1 << log_c) * params.blowup, 1 << log_r))
     enc = _rs_encode(mats, params.blowup)                # (B, R, C*blowup)
     trees = M.commit_batch(jnp.swapaxes(enc, 1, 2))      # leaves are columns
     return [Commitment(mat=mats[i], enc=enc[i], tree=trees[i],
@@ -347,21 +351,41 @@ def _build_e_vec(n_tot: int, pts_np: Sequence[np.ndarray],
     buckets: Dict[int, List[Tuple[int, List[int]]]] = {}
     for (t, idx), members in groups.items():
         buckets.setdefault(t, []).append((idx, members))
+    # Group, member and weight counts are padded to powers of two, so
+    # commitments and queries with similar claim sets share one program
+    # per suffix length; a padding group has only zero-weight slots and
+    # adds exact zeros (into slice 0).
+    ws_ext = jnp.concatenate([ws_ext, jnp.zeros(
+        (_pow2(k + 1) - k - 1, 4), jnp.uint32)])
+    args = []
     for t in sorted(buckets):
         glist = sorted(buckets[t])
-        G = len(glist)
-        mx = max(len(mem) for _, mem in glist)
+        G = _pow2(len(glist))
+        mx = _pow2(max(len(mem) for _, mem in glist))
         sufs = np.zeros((G, mx, t, 4), np.uint32)
-        widx = np.full((G, mx), k, np.int64)   # padding -> zero weight row
-        los = np.empty((G,), np.int64)
+        widx = np.full((G, mx), k, np.int32)   # padding -> zero weight row
+        los = np.zeros((G,), np.int32)
         for g, (idx, members) in enumerate(glist):
             los[g] = idx << t
             for j, i in enumerate(members):
                 sufs[g, j] = pts_np[i][m - t:]
                 widx[g, j] = i
+        args.append((sufs, widx, los, t))
+    ahead = []
+    if KOPS.on_tpu():                  # every bucket compiles in parallel
+        ahead = [AH.start(_bucket_e_impl, *(jax.ShapeDtypeStruct(
+            x.shape, x.dtype) for x in (e_vec, sufs, ws_ext, widx, los)), t)
+            for sufs, widx, los, t in args]
+    for b, (sufs, widx, los, t) in enumerate(args):
+        if ahead:
+            ahead[b].result()
         e_vec = _bucket_e_impl(e_vec, jnp.asarray(sufs), ws_ext,
                                jnp.asarray(widx), jnp.asarray(los), t)
     return e_vec
+
+
+def _pow2(x: int) -> int:
+    return 1 << max(x - 1, 0).bit_length()
 
 
 def _encode_f4_row(u: jnp.ndarray, blowup: int) -> jnp.ndarray:
@@ -405,7 +429,7 @@ def prove_openings(com: Commitment, points: Sequence[jnp.ndarray],
     transcript.absorb(u_prox)
     n_cols = com.enc.shape[1]
     idx = transcript.challenge_indices(n_cols, params.queries)
-    columns = np.asarray(com.enc.T[idx])                     # (t, R)
+    columns = np.asarray(com.enc[:, idx]).T                  # (t, R)
     paths = M.batch_open(com.tree, idx)
     return OpeningBundle(us=np.stack(us) if us else np.zeros((0,) + (com.mat.shape[1], 4), np.uint32),
                          u_prox=np.asarray(u_prox), columns=columns, paths=paths)
@@ -442,7 +466,7 @@ def _prove_openings_batched(com: Commitment, points: Sequence[jnp.ndarray],
     transcript.absorb(u)
     n_cols = com.enc.shape[1]
     idx = transcript.challenge_indices(n_cols, params.queries)
-    columns = np.asarray(com.enc.T[idx])                     # (t, R)
+    columns = np.asarray(com.enc[:, idx]).T                  # (t, R)
     paths = M.batch_open(com.tree, idx)
     return OpeningBundle(us=np.asarray(u)[None], u_prox=None,
                          columns=columns, paths=paths, batch_sc=sc)

