@@ -174,9 +174,7 @@ class ProofService:
         subset = select_layers(policy, len(self.block_cfgs),
                                self.fisher_scores)
         eng = self.engine_for(policy.pcs_queries)
-        t0 = time.monotonic()
         proof, report = eng.prove(np.asarray(query), layer_subset=subset)
-        dt = time.monotonic() - t0
         self.queries_served += 1
         self.last_report = report
         return Attestation(
@@ -184,7 +182,7 @@ class ProofService:
             tokens=(np.asarray(tokens) if tokens is not None
                     else np.zeros(0, np.int32)),
             proof=proof, proved_layers=list(subset), policy=policy,
-            prove_seconds=dt)
+            prove_seconds=report.total_seconds)
 
     def attest_many(self, queries: Sequence[np.ndarray],
                     policies: Optional[Sequence[VerifyPolicy]] = None,
@@ -215,10 +213,8 @@ class ProofService:
         subsets = [select_layers(p, len(self.block_cfgs),
                                  self.fisher_scores) for p in policies]
         eng = self.engine_for(policies[0].pcs_queries)
-        t0 = time.monotonic()
         proofs, report = eng.prove_many(
             [np.asarray(q) for q in queries], subsets)
-        dt = time.monotonic() - t0
         self.queries_served += K
         self.last_report = report
         model_id = self.model_card.model_id
@@ -230,7 +226,8 @@ class ProofService:
                         else np.zeros(0, np.int32)),
                 proof=proofs[i], proved_layers=list(subsets[i]),
                 policy=policies[i],
-                prove_seconds=dt / K)   # window wall, amortized (telemetry)
+                # the window's engine time, amortized (telemetry)
+                prove_seconds=report.total_seconds / K)
             for i in range(K)]
 
 

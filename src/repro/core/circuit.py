@@ -969,7 +969,7 @@ def flush_lookups(ctx: Ctx, helper_name: str = "lkh", aspect: int = 0):
             w = _lookup_w_f4(ctx, req, betas[i])
             ab = jnp.broadcast_to(alpha, w.shape)
             if inv_ahead:
-                inv_ahead[req.log_n].result()
+                AH.wait(inv_ahead[req.log_n])
             a = F.f4inv(F.f4sub(ab, w))                  # (n_i, 4)
             a_vecs[i] = a
             a_np = np.asarray(a)

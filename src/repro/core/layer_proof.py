@@ -176,29 +176,38 @@ def prove_layer(cfg: B.BlockCfg, layer_index: int, wt: WeightCommit,
                 b_in: BoundaryCommit, b_out: BoundaryCommit,
                 trace: Dict[str, np.ndarray], params: PCS.PCSParams,
                 check_input_range: bool = False) -> LayerProof:
-    tr = Transcript("nanozk.layer")
-    tr.absorb_int(layer_index)
-    ctx = C.ProverCtx(tr, params)
-    ctx.attach("wt", wt.com, wt.ints)
-    ctx.attach("b_in", b_in.com, b_in.ints)
-    ctx.attach("b_out", b_out.com, b_out.ints)
+    """The layer's proof, in four phases timed as spans (``repro.spans``):
+    witness, argument, lookups and openings."""
+    # imported here, not at the top: a Pallas kernel traced from the
+    # functions above carries their source lines in its compile-cache key
+    from repro import spans
+    with spans.span("layer.witness"):
+        tr = Transcript("nanozk.layer")
+        tr.absorb_int(layer_index)
+        ctx = C.ProverCtx(tr, params)
+        ctx.attach("wt", wt.com, wt.ints)
+        ctx.attach("b_in", b_in.com, b_in.ints)
+        ctx.attach("b_out", b_out.com, b_out.ints)
 
-    wb = C.WitnessBuilder("aux")
-    prepared = B.prepare_trace(cfg, trace)
-    layout = B.declare_aux(cfg, wb, prepared)
-    slices = wb.build(ctx)
-    V = B.Views(layout, slices)
-    Vw = B.Views(wt.layout, wt.slices)
-    x_view = _boundary_views(b_in, "b_in")
-    y_view = _boundary_views(b_out, "b_out")
-    B.block_argument(ctx, cfg, V, Vw, x_view, y_view,
-                     lut_ints=B.lut_int_arrays(cfg, trace))
-    wb.run_checks(ctx, slices)
-    C.g_range8(ctx, "b_out", b_out.n)
-    if check_input_range:
-        C.g_range8(ctx, "b_in", b_in.n)
-    C.flush_lookups(ctx)
-    ctx.finalize()
+        wb = C.WitnessBuilder("aux")
+        prepared = B.prepare_trace(cfg, trace)
+        layout = B.declare_aux(cfg, wb, prepared)
+        slices = wb.build(ctx)
+        V = B.Views(layout, slices)
+        Vw = B.Views(wt.layout, wt.slices)
+        x_view = _boundary_views(b_in, "b_in")
+        y_view = _boundary_views(b_out, "b_out")
+    with spans.span("layer.argument"):
+        B.block_argument(ctx, cfg, V, Vw, x_view, y_view,
+                         lut_ints=B.lut_int_arrays(cfg, trace))
+        wb.run_checks(ctx, slices)
+    with spans.span("layer.lookups"):
+        C.g_range8(ctx, "b_out", b_out.n)
+        if check_input_range:
+            C.g_range8(ctx, "b_in", b_in.n)
+        C.flush_lookups(ctx)
+    with spans.span("layer.openings"):
+        ctx.finalize()
     return LayerProof(layer_index=layer_index, in_root=b_in.root,
                       out_root=b_out.root, wt_root=wt.root, tape=ctx.tape)
 

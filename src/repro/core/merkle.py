@@ -58,7 +58,7 @@ def prepare(shape) -> list:
 
 def _wait(futs: list, i: int) -> None:
     if futs:
-        futs[i].result()
+        AH.wait(futs[i])
 
 
 @dataclasses.dataclass

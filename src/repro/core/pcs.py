@@ -378,7 +378,7 @@ def _build_e_vec(n_tot: int, pts_np: Sequence[np.ndarray],
             for sufs, widx, los, t in args]
     for b, (sufs, widx, los, t) in enumerate(args):
         if ahead:
-            ahead[b].result()
+            AH.wait(ahead[b])
         e_vec = _bucket_e_impl(e_vec, jnp.asarray(sufs), ws_ext,
                                jnp.asarray(widx), jnp.asarray(los), t)
     return e_vec

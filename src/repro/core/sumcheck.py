@@ -158,7 +158,7 @@ def prove(factors: Sequence[jnp.ndarray], transcript: Transcript
     for r in range(m):
         if ahead:
             for fut in ahead[r]:
-                fut.result()
+                AH.wait(fut)
         g, los, diffs = _round_kernel(factors)
         round_polys.append(np.asarray(g)[1:])   # g(0) implied by running sum
         transcript.absorb(g)

@@ -14,6 +14,8 @@ from __future__ import annotations
 import threading
 from typing import Dict, List, Optional, Sequence
 
+from repro.runtime.engine import PHASES
+
 
 class Histogram:
     """Fixed-boundary histogram (cumulative ``le`` buckets + count/sum/max).
@@ -48,8 +50,7 @@ class Histogram:
                 "buckets": dict(zip(labels, self.buckets))}
 
 
-#: seconds-scale latency buckets (forward/commit/prove run in the
-#: 0.01 s – minutes range on CPU; sub-ms on real accelerators)
+#: seconds-scale latency buckets
 LATENCY_BOUNDS = (0.001, 0.01, 0.05, 0.1, 0.5, 1.0, 5.0, 15.0, 60.0, 300.0)
 #: coalesce batch sizes are small integers bounded by GatewayConfig.max_batch
 BATCH_BOUNDS = (1, 2, 3, 4, 6, 8, 12, 16, 32)
@@ -61,7 +62,11 @@ class GatewayMetrics:
     ``snapshot()`` returns a plain JSON-able dict: admission counts
     (admitted / rejected-by-reason — backpressure must be *observable*),
     live queue depth, coalesce batch-size distribution, and per-stage
-    latency histograms for the proving pipeline.
+    latency histograms for the proving pipeline: the engine's stages
+    (``forward``, ``commit``, ``prove``, ``total``), its layer-proof
+    phases summed over a window (``witness``, ``argument``, ``lookups``,
+    ``openings``), its compile wait (``compile``), and the transport's
+    encoding and sending of each attestation (``deliver``).
     """
 
     def __init__(self):
@@ -78,7 +83,8 @@ class GatewayMetrics:
         self.admission_wait_seconds = Histogram(LATENCY_BOUNDS)
         self.stage_seconds = {
             stage: Histogram(LATENCY_BOUNDS)
-            for stage in ("forward", "commit", "prove", "total")}
+            for stage in ("forward", "commit", "prove", "total",
+                          *PHASES, "compile", "deliver")}
 
     # -- recording ----------------------------------------------------------
     def on_admit(self, depth: int) -> None:
@@ -116,6 +122,15 @@ class GatewayMetrics:
                 self.stage_seconds["commit"].observe(report.commit_seconds)
                 self.stage_seconds["prove"].observe(report.prove_seconds)
                 self.stage_seconds["total"].observe(report.total_seconds)
+                for phase in PHASES:
+                    self.stage_seconds[phase].observe(
+                        report.phase_seconds[phase])
+                self.stage_seconds["compile"].observe(report.compile_seconds)
+
+    def on_delivered(self, seconds: float) -> None:
+        """One attestation encoded and sent, before its ``DONE``."""
+        with self._lock:
+            self.stage_seconds["deliver"].observe(seconds)
 
     # -- export -------------------------------------------------------------
     def snapshot(self) -> Dict:

@@ -31,7 +31,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro import api
+from repro import api, spans
 from repro.api import codec
 from repro.api.types import VerifyPolicy
 
@@ -271,13 +271,20 @@ class GatewayServer:
             return False
         try:
             att = ticket.result(timeout=self.result_timeout)
-            wire = att.to_bytes(2)
         except BaseException as e:  # noqa: BLE001 — report, don't kill the conn
             return self._try_send(conn, MSG_ERROR, {"detail": str(e)})
-        for off in range(0, len(wire), self.chunk_bytes):
-            if not self._try_send_raw(conn, MSG_CHUNK,
-                                      wire[off:off + self.chunk_bytes]):
-                return False
+        with spans.span("gateway.deliver") as deliver:
+            try:
+                wire = att.to_bytes(2)
+            except BaseException as e:  # noqa: BLE001 — as above
+                return self._try_send(conn, MSG_ERROR, {"detail": str(e)})
+            for off in range(0, len(wire), self.chunk_bytes):
+                if not self._try_send_raw(conn, MSG_CHUNK,
+                                          wire[off:off + self.chunk_bytes]):
+                    return False
+        # observed before DONE: a snapshot taken once the client has its
+        # attestation counts the delivery
+        self.gateway.metrics.on_delivered(deliver.seconds)
         return self._try_send(conn, MSG_DONE, {
             "size_bytes": len(wire),
             "batch_size": ticket.batch_size,

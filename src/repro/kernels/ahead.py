@@ -8,8 +8,8 @@ them to a small thread pool here, which lowers and compiles them while
 the rounds run: XLA compiles release the GIL and overlap one another.  A
 later call of the jitted function with the same shapes and static
 arguments finds the executable in JAX's in-memory cache and compiles
-nothing.  The caller waits on each program's future before its call, so
-no program is compiled twice.
+nothing.  The caller waits on each program's future (``wait``) before
+its call, so no program is compiled twice.
 
 Lock order (ranked in repro.analysis.locks): ``_LOCK`` is a rank-70
 leaf; nothing else is taken while it is held.
@@ -56,3 +56,19 @@ def start(fn, *args, **static) -> concurrent.futures.Future:
             fut = _pool().submit(lambda: fn.lower(*args, **static).compile())
             _STARTED[key] = fut
     return fut
+
+
+# Imported below ``start``: a Pallas kernel lowered in the pool carries
+# the source location of ``start``'s lambda in its compile-cache key, so
+# a line added above it would have every kernel started here compiled
+# anew.
+from repro import spans  # noqa: E402
+
+
+def wait(fut: concurrent.futures.Future):
+    """The compile's result, once done; the time this thread blocks on
+    it is compile wait of the engine call it works for."""
+    if fut.done():
+        return fut.result()
+    with spans.compile_wait():
+        return fut.result()

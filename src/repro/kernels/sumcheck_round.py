@@ -363,7 +363,7 @@ def _prove_rounds_impl(factors, states, pallas: bool, interpret: bool):
         for r in range(m):
             if ahead:
                 for fut in ahead[r]:
-                    fut.result()
+                    AH.wait(fut)
             g = _eval_round(tiles, n >> r, interpret)
             st, c = _transcript_round(g, st, interpret)
             tiles = _fold_round(tiles, c, n >> r, interpret)

@@ -82,7 +82,7 @@ class WeightCommitCache:
         + PCS params) that skips even the re-commit for the common case of
         serving many queries against the same resident model.
 
-    Thread-safe; hit/miss counters feed EngineReport.
+    Thread-safe; counts its hits and misses.
     """
 
     def __init__(self):
@@ -151,8 +151,6 @@ class SumcheckRoundBatcher:
         self._pending: Dict[int, Tuple[tuple, jnp.ndarray]] = {}
         self._results: Dict[int, tuple] = {}
         self._timeout = timeout
-        self.batched_claims = 0      # claims that shared a launch with >=1 peer
-        self.launch_waves = 0        # fused launches issued
 
     def register(self) -> None:
         with self._cv:
@@ -196,9 +194,6 @@ class SumcheckRoundBatcher:
                 self._results[ident] = (
                     np.ascontiguousarray(rp_np[k, :, 1:]), pts[k],
                     fin_np[k], sts_out[k])
-            self.launch_waves += 1
-            if K > 1:
-                self.batched_claims += K
         self._cv.notify_all()
 
     def prove(self, factors: tuple, transcript) -> tuple:
@@ -264,6 +259,11 @@ class ForwardTrace:
 
 @dataclasses.dataclass
 class EngineReport:
+    """Host seconds of one engine call, from its spans (``repro.spans``):
+    the three stages (``total_seconds`` is their sum), the ``PHASES`` of
+    its layer proofs summed over them (and so over every worker thread
+    that proved one), and the compile wait on the threads that worked
+    for it."""
     forward_seconds: float
     commit_seconds: float
     prove_seconds: float
@@ -272,8 +272,8 @@ class EngineReport:
     jobs: int
     claims: int
     losses: int
-    cache_hits: int
-    cache_misses: int
+    phase_seconds: Dict[str, float]
+    compile_seconds: float
 
 
 @dataclasses.dataclass
@@ -409,25 +409,29 @@ class ProverEngine:
         with or without the batcher.
         """
         batcher = None
+        col = spans.collector()         # this call's, lent to the workers
         if self.backend == "process":
             pool = self._ensure_pool()
 
             def prove_one(key) -> LP.LayerProof:
                 # the claiming thread blocks on its worker process; the
                 # queue/requeue protocol is unchanged across backends
-                return pool.apply(_process_prove_layer, (payload_fn(key),))
+                with spans.attach(col):
+                    return pool.apply(_process_prove_layer,
+                                      (payload_fn(key),))
         else:
             batcher = (SumcheckRoundBatcher()
                        if self.workers > 1 and KOPS.use_fused() else None)
 
             def prove_one(key) -> LP.LayerProof:
-                if batcher is None:
-                    return _process_prove_layer(payload_fn(key))
-                batcher.register()
-                try:
-                    return _process_prove_layer(payload_fn(key))
-                finally:
-                    batcher.deregister()
+                with spans.attach(col):
+                    if batcher is None:
+                        return _process_prove_layer(payload_fn(key))
+                    batcher.register()
+                    try:
+                        return _process_prove_layer(payload_fn(key))
+                    finally:
+                        batcher.deregister()
 
         sched = ProofScheduler(workers=self.workers,
                                fail_claims=self.fail_claims)
@@ -460,34 +464,23 @@ class ProverEngine:
     def prove(self, x0: np.ndarray,
               layer_subset: Optional[Sequence[int]] = None
               ) -> Tuple[CH.ModelProof, EngineReport]:
-        # snapshot so the report shows THIS call's cache activity, not the
-        # shared cache's lifetime totals
-        hits0 = self.weight_cache.hits
-        misses0 = self.weight_cache.misses
         wt_commits = self.wt_commits          # setup (cached/amortized)
-        t0 = time.monotonic()
-        fwd = self.run_forward(x0)
-        t1 = time.monotonic()
-        boundaries = self.commit_boundaries(fwd)
-        t2 = time.monotonic()
-        subset = list(range(len(self.cfgs)) if layer_subset is None
-                      else layer_subset)
-        jobs = [ProofJob(layer=l, check_input_range=(l == 0))
-                for l in subset]
-        done, stats = self.prove_layers(jobs, boundaries, fwd)
-        t3 = time.monotonic()
+        with spans.collect() as col:
+            with spans.span("engine.forward"):
+                fwd = self.run_forward(x0)
+            with spans.span("engine.commit"):
+                boundaries = self.commit_boundaries(fwd)
+            subset = list(range(len(self.cfgs)) if layer_subset is None
+                          else layer_subset)
+            jobs = [ProofJob(layer=l, check_input_range=(l == 0))
+                    for l in subset]
+            with spans.span("engine.prove"):
+                done, stats = self.prove_layers(jobs, boundaries, fwd)
         proof = CH.ModelProof(
             layer_proofs=[done[l] for l in subset],
             boundary_roots=[b.root for b in boundaries],
             wt_roots=[w.root for w in wt_commits])
-        report = EngineReport(
-            forward_seconds=t1 - t0, commit_seconds=t2 - t1,
-            prove_seconds=t3 - t2, total_seconds=t3 - t0,
-            workers=stats.workers, jobs=stats.jobs, claims=stats.claims,
-            losses=stats.losses,
-            cache_hits=self.weight_cache.hits - hits0,
-            cache_misses=self.weight_cache.misses - misses0)
-        return proof, report
+        return proof, _report(EngineReport, col, stats)
 
     def prove_many(self, x0s: Sequence[np.ndarray],
                    layer_subsets: Optional[Sequence[Sequence[int]]] = None
@@ -503,40 +496,54 @@ class ProverEngine:
         produced for its query alone.
         """
         K = len(x0s)
-        hits0 = self.weight_cache.hits
-        misses0 = self.weight_cache.misses
         wt_commits = self.wt_commits          # setup (cached/amortized)
-        t0 = time.monotonic()
-        fwds = [self.run_forward(np.asarray(x)) for x in x0s]
-        t1 = time.monotonic()
-        per_query_bounds = self.commit_boundaries_coalesced(fwds)
-        t2 = time.monotonic()
         if layer_subsets is None:
             layer_subsets = [list(range(len(self.cfgs)))] * K
         subsets = [list(s) for s in layer_subsets]
         assert len(subsets) == K
+        with spans.collect() as col:
+            with spans.span("engine.forward"):
+                fwds = [self.run_forward(np.asarray(x)) for x in x0s]
+            with spans.span("engine.commit"):
+                per_query_bounds = self.commit_boundaries_coalesced(fwds)
 
-        def payload(key):
-            qi, l = key
-            return (self.cfgs[l], l, wt_commits[l],
-                    per_query_bounds[qi][l], per_query_bounds[qi][l + 1],
-                    fwds[qi].traces[l], self.params, l == 0)
+            def payload(key):
+                qi, l = key
+                return (self.cfgs[l], l, wt_commits[l],
+                        per_query_bounds[qi][l], per_query_bounds[qi][l + 1],
+                        fwds[qi].traces[l], self.params, l == 0)
 
-        job_keys = [(qi, l) for qi, sub in enumerate(subsets) for l in sub]
-        done, stats = self._run_jobs(job_keys, payload)
-        t3 = time.monotonic()
+            job_keys = [(qi, l) for qi, sub in enumerate(subsets)
+                        for l in sub]
+            with spans.span("engine.prove"):
+                done, stats = self._run_jobs(job_keys, payload)
         proofs = [
             CH.ModelProof(
                 layer_proofs=[done[(qi, l)] for l in subsets[qi]],
                 boundary_roots=[b.root for b in per_query_bounds[qi]],
                 wt_roots=[w.root for w in wt_commits])
             for qi in range(K)]
-        report = BatchEngineReport(
-            batch_size=K,
-            forward_seconds=t1 - t0, commit_seconds=t2 - t1,
-            prove_seconds=t3 - t2, total_seconds=t3 - t0,
-            workers=stats.workers, jobs=stats.jobs, claims=stats.claims,
-            losses=stats.losses,
-            cache_hits=self.weight_cache.hits - hits0,
-            cache_misses=self.weight_cache.misses - misses0)
-        return proofs, report
+        return proofs, _report(BatchEngineReport, col, stats, batch_size=K)
+
+
+# Imported here, below the class: a Pallas kernel traced during the weight
+# set-up or a boundary commit carries the source lines of the methods
+# above in its compile-cache key.
+from repro import spans  # noqa: E402
+
+#: the phases of a layer proof, each a span ``layer.<phase>`` in
+#: ``layer_proof.prove_layer``
+PHASES = ("witness", "argument", "lookups", "openings")
+
+
+def _report(report_cls, col: spans.Collector, stats: ScheduleStats,
+            **kw) -> EngineReport:
+    stage = [col.seconds(f"engine.{s}") for s in ("forward", "commit",
+                                                    "prove")]
+    return report_cls(
+        forward_seconds=stage[0], commit_seconds=stage[1],
+        prove_seconds=stage[2], total_seconds=sum(stage),
+        workers=stats.workers, jobs=stats.jobs, claims=stats.claims,
+        losses=stats.losses,
+        phase_seconds={p: col.seconds(f"layer.{p}") for p in PHASES},
+        compile_seconds=col.seconds(spans.COMPILE), **kw)

@@ -15,7 +15,7 @@ crashed after claiming but before completing.  Tests use this to exercise
 the requeue-on-loss path deterministically.
 
 Lock order (ranked in repro.analysis.locks): the local ``lock`` in
-``run()`` (errors/busy bookkeeping) is rank 50 — it may be taken while
+``run()`` (error bookkeeping) is rank 50 — it may be taken while
 engine locks (ranks <= 40) are held and may itself be held while the
 batcher (rank 60) or leaf (rank 70) locks are acquired.
 """
@@ -35,8 +35,6 @@ class ScheduleStats:
     jobs: int
     claims: int          # total claim events (jobs + redos)
     losses: int          # claims lost to (injected) worker deaths
-    wall_seconds: float
-    worker_seconds: Dict[str, float]  # busy time per worker
 
 
 class ProofScheduler:
@@ -62,11 +60,9 @@ class ProofScheduler:
             ) -> tuple[Dict[int, object], ScheduleStats]:
         queue = ProofWorkReplayQueue(list(layer_ids))
         errors: List[BaseException] = []
-        busy: Dict[str, float] = {}
         lock = threading.Lock()
 
         def worker_loop(wid: str):
-            t_busy = 0.0
             while True:
                 with lock:
                     if errors or queue.losses > self.max_losses:
@@ -82,7 +78,6 @@ class ProofScheduler:
                 if seq in self.fail_claims:
                     queue.worker_lost(wid)
                     continue
-                t0 = time.monotonic()
                 try:
                     proof = prove_fn(layer)
                 except BaseException as e:  # noqa: BLE001 — surface to caller
@@ -90,12 +85,8 @@ class ProofScheduler:
                         errors.append(e)
                     queue.worker_lost(wid)
                     break
-                t_busy += time.monotonic() - t0
                 queue.complete(wid, proof)
-            with lock:
-                busy[wid] = t_busy
 
-        t0 = time.monotonic()
         if self.workers == 1:
             worker_loop("w0")
         else:
@@ -106,11 +97,9 @@ class ProofScheduler:
                 t.start()
             for t in threads:
                 t.join()
-        wall = time.monotonic() - t0
         if errors:
             raise errors[0]
         assert queue.finished, "scheduler exited with unproven layers"
         stats = ScheduleStats(workers=self.workers, jobs=len(layer_ids),
-                              claims=queue.claims, losses=queue.losses,
-                              wall_seconds=wall, worker_seconds=busy)
+                              claims=queue.claims, losses=queue.losses)
         return queue.done, stats
