@@ -110,12 +110,13 @@ class ReplayLog:
         self._ctx_emit("finalize", ctx)
 
 
-def golden_setup():
+def golden_setup(heads: int = 1):
     """Small-but-real model config mirroring the transcript-determinism
-    golden fixture (one gpt2 block, d=8)."""
+    golden fixture (one gpt2 block, d=8); ``heads`` > 1 takes the
+    attention argument batched over heads."""
     from repro.core import blocks as B
-    cfg = B.BlockCfg(family="gpt2", d=8, dff=16, heads=1, kv_heads=1, dh=8,
-                     seq=4)
+    cfg = B.BlockCfg(family="gpt2", d=8, dff=16, heads=heads,
+                     kv_heads=heads, dh=8, seq=4)
     rng = np.random.default_rng(1234)
     weights = [B.init_weights(cfg, rng)]
     qrng = np.random.default_rng(5678)
@@ -124,14 +125,15 @@ def golden_setup():
     return cfg, weights, query
 
 
-def run_golden_prove(log: ReplayLog | None = None) -> ReplayLog:
+def run_golden_prove(log: ReplayLog | None = None,
+                     heads: int = 1) -> ReplayLog:
     """Attest the golden toy model with recorder + observer installed.
 
     Pass ``log`` to keep a reference to the (partial) event stream even
     when the prove raises — the mutation corpus lints crashed proves.
     """
     from repro import api
-    cfg, weights, query = golden_setup()
+    cfg, weights, query = golden_setup(heads)
     log = log if log is not None else ReplayLog()
     T.set_recorder(log)
     C.set_observer(log)

@@ -228,8 +228,8 @@ def block_forward(cfg: BlockCfg, w: Dict[str, np.ndarray], x: np.ndarray
     else:
         q_att, k_att = q, k
 
-    # attention heads; the softmax witness spans a power-of-two number of
-    # heads, and a padding head (all-zero q, k, v) gets the witness its
+    # attention heads; the attention witness spans a power-of-two number
+    # of heads, and a padding head (all-zero q, k, v) gets the witness its
     # zero scores give, so the relations batched over heads hold on it
     mask = cfg.causal_mask
     group = H // KV
@@ -254,9 +254,8 @@ def block_forward(cfg: BlockCfg, w: Dict[str, np.ndarray], x: np.ndarray
         sidx[h], err_s[h], e_arr[h] = th["sidx"], th["err_s"], th["e"]
         P_arr[h], w1_arr[h], w2_arr[h] = th["P"], th["w1"], th["w2"]
         S_arr[h] = th["S"]
-        if h < H:
-            O[h * dh:(h + 1) * dh] = th["o"]
-            err_o[h * dh:(h + 1) * dh] = th["err_o"]
+        O[h * dh:(h + 1) * dh] = th["o"]
+        err_o[h * dh:(h + 1) * dh] = th["err_o"]
     tr.update(sidx=sidx, err_s=err_s, e=e_arr, P=P_arr, w1=w1_arr,
               w2=w2_arr, S=S_arr, O=O, err_o=err_o)
 
@@ -621,11 +620,18 @@ def block_argument(ctx, cfg: BlockCfg, V: Views, Vw: Views,
                     C.g_rescale(ctx, acc, r, out, ev, Q.ROPE_F, 16,
                                 f"rope {dst} h{h}")
 
-    # ---- attention scores ----
+    # ---- attention scores: one argument over all heads, or with grouped
+    # k/v heads (group > 1) one per head ----
     m_mult = Q.score_mult(dh)
-    for h in range(H):
-        kvh = h // group
-        _score_mm(ctx, cfg, V, q_name, k_name, h, kvh, m_mult)
+    if group == 1:
+        acc, r = C.g_int_matmul_heads(
+            ctx, V.hi(q_name), V.lo(q_name), V.hi(k_name), V.lo(k_name),
+            (seq, dh, seq), log_H, a_t=True)
+        C.g_rescale(ctx, Fld.f4mul(acc, C._fc(m_mult)), r, V.limb("sidx"),
+                    V.ranged("err_s"), 12, 16, "scores")
+    else:
+        for h in range(H):
+            _score_mm(ctx, cfg, V, q_name, k_name, h, h // group, m_mult)
 
     # ---- softmax relations (batched over heads) ----
     mask_pub = C.Public(tuple(cfg.causal_mask.reshape(-1).tolist()), "mask")
@@ -646,21 +652,17 @@ def block_argument(ctx, cfg: BlockCfg, V: Views, Vw: Views,
                            (-2, S_b)], 1, "softmax residue bound",
                      log_n=log_H + ls2)
 
-    # ---- P @ V per head ----
-    for h in range(H):
-        kvh = h // group
-        base_p = h * seq * seq
-        p_hi = C.vaff([(1, V.digit_sub("P", 1, base_p, ls2))], const=128)
-        p_lo = C.vaff([(1, V.digit_sub("P", 0, base_p, ls2))])
-        lvs = _log2(dh * seq)
-        acc, r_i, r_j = C.g_int_matmul(
-            ctx, V.hi_sub("v", kvh * dh * seq, lvs),
-            V.lo_sub("v", kvh * dh * seq, lvs), p_hi, p_lo,
-            (dh, seq, seq), b_t=True)
-        r = jnp.concatenate([r_i, r_j])
-        C.g_rescale(ctx, acc, r, V.limb_sub("O", h * dh * seq, lvs),
-                    V.ranged_sub("err_o", h * dh * seq, lvs), 8, 16,
-                    f"attn out h{h}")
+    # ---- P @ V: one argument over all heads, or one per head ----
+    if group == 1:
+        p_hi = C.vaff([(1, V.sl["P.d1"])], const=128)
+        p_lo = C.vaff([(1, V.sl["P.d0"])])
+        acc, r = C.g_int_matmul_heads(ctx, V.hi("v"), V.lo("v"), p_hi, p_lo,
+                                      (dh, seq, seq), log_H, b_t=True)
+        C.g_rescale(ctx, acc, r, V.limb("O"), V.ranged("err_o"), 8, 16,
+                    "attn out")
+    else:
+        for h in range(H):
+            _pv_mm(ctx, cfg, V, h, h // group)
 
     # ---- output projection + residual ----
     _mm_rescale(ctx, cfg, Vw.hi("woT"), Vw.lo("woT"), V.hi("O"), V.lo("O"),
@@ -734,6 +736,23 @@ def _score_mm(ctx, cfg, V: Views, q_name, k_name, h, kvh, m_mult):
                 V.ranged_sub("err_s", h * seq * seq, ls2), 12, 16,
                 f"scores h{h}")
     return r
+
+
+def _pv_mm(ctx, cfg, V: Views, h, kvh):
+    seq, dh = cfg.seq, cfg.dh
+    ls2 = 2 * _log2(seq)
+    lvs = _log2(dh * seq)
+    base_p = h * seq * seq
+    p_hi = C.vaff([(1, V.digit_sub("P", 1, base_p, ls2))], const=128)
+    p_lo = C.vaff([(1, V.digit_sub("P", 0, base_p, ls2))])
+    acc, r_i, r_j = C.g_int_matmul(
+        ctx, V.hi_sub("v", kvh * dh * seq, lvs),
+        V.lo_sub("v", kvh * dh * seq, lvs), p_hi, p_lo,
+        (dh, seq, seq), b_t=True)
+    r = jnp.concatenate([r_i, r_j])
+    C.g_rescale(ctx, acc, r, V.limb_sub("O", h * dh * seq, lvs),
+                V.ranged_sub("err_o", h * dh * seq, lvs), 8, 16,
+                f"attn out h{h}")
 
 
 def lut_int_arrays(cfg: BlockCfg, tr: Dict[str, np.ndarray]

@@ -102,10 +102,14 @@ class SoundnessReport:
 def layer_circuit_stats(cfg: B.BlockCfg) -> Dict[str, int]:
     """Conservative counts of soundness-relevant events per layer proof."""
     H, seq = cfg.heads, cfg.seq
-    n_matmul = 3 + 2 * H + 2 + (1 if cfg.family == "llama" else 0)
+    # the score and P.V matmuls of all heads are one argument each, over
+    # log2(pad2(H)) head variables; grouped k/v heads keep one per head
+    head_mm = 2 if cfg.kv_heads == H else 2 * H
+    n_matmul = 3 + head_mm + 2 + (1 if cfg.family == "llama" else 0)
     n_sumchecks = 9 * n_matmul + 30 + (8 * H if cfg.family == "llama" else 0)
     max_vars = max((cfg.dff_pad * cfg.seq).bit_length(),
-                   (H * seq * seq).bit_length()) + 3
+                   (H * seq * seq).bit_length(),
+                   (B._pad2(H) * max(cfg.dh, seq)).bit_length()) + 3
     n_lookups = 5
     n_openings = 16
     n_relations = 40

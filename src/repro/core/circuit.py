@@ -1017,3 +1017,96 @@ def flush_lookups(ctx: Ctx, helper_name: str = "lkh", aspect: int = 0):
             w_rho = F.f4add(w_rho, F.f4mul(betas[i], ctx.claim(req.out, rho)))
         ctx.check_eq(F.f4sub(alpha, w_rho), finals[2],
                      f"{req.what} witness tie")
+
+
+# ---------------------------------------------------------------------------
+# Head-batched matmuls: the same product over 2^lh heads stored one after
+# another, proved as one argument.
+# ---------------------------------------------------------------------------
+@jax.jit
+def _bind_mid(mat: jnp.ndarray, r: jnp.ndarray) -> jnp.ndarray:
+    """Base (H, R, C) with the R variables bound to r -> Fp4 (H*C, 4)."""
+    per_head = jax.vmap(partial_eval_rows, in_axes=(0, None))
+    return per_head(mat, r).reshape(-1, 4)
+
+
+def g_matmul_term_heads(ctx: Ctx, A: View, B: View,
+                        shape: Tuple[int, int, int], r_h: jnp.ndarray,
+                        r_i: jnp.ndarray, r_j: jnp.ndarray,
+                        a_t: bool = False, b_t: bool = False) -> jnp.ndarray:
+    """Returns sum_h eq(r_h, h) (op(A_h) @ op(B_h))~(r_i, r_j): one
+    sum-check over (h, k) of eq(r_h, h) * A_r(h, k) * B_c(h, k), the eq
+    factor leading and broadcast over k (as g_dot_eq's 'lead').
+
+    A and B hold 2^len(r_h) heads, each laid out as g_matmul_term's
+    operand.  The eq factor stays a factor of its own: the verifier
+    computes eq(r_h, rho_h), and A_r~(rho_h, rho_k) is a claim on A.
+    """
+    n, k, m = shape
+    lh, lk = r_h.shape[0], k.bit_length() - 1
+    assert 1 << lk == k
+    if ctx.is_prover:
+        hs = 1 << lh
+        Am, Bm = ctx.materialize(A), ctx.materialize(B)
+        A_r = (partial_eval_cols(Am.reshape(hs * k, n), r_i) if a_t
+               else _bind_mid(Am.reshape(hs, n, k), r_i))
+        B_c = (_bind_mid(Bm.reshape(hs, m, k), r_j) if b_t
+               else partial_eval_cols(Bm.reshape(hs * k, m), r_j))
+        eq = jnp.repeat(eq_points(r_h), k, axis=0)
+        t = ctx.put_value(fsum(F.f4mul(F.f4mul(eq, A_r), B_c), axis=0))
+        proof, rho = SC.prove([eq, A_r, B_c], ctx.tr)
+        ctx.put(proof)
+        finals = jnp.asarray(proof.final_evals)
+    else:
+        t = ctx.get_value()
+        proof = ctx.get()
+        ok, rho, finals = SC.verify(t, proof, 3, ctx.tr)
+        if not ok:
+            raise ProofError("g_matmul_term_heads sumcheck failed")
+        if rho.shape[0] != lh + lk:
+            raise ProofError("g_matmul_term_heads wrong (h, k) vars")
+    rho_h, rho_k = rho[:lh], rho[lh:]
+    ctx.check_eq(eq_eval(r_h, rho_h), finals[0], "head matmul eq factor")
+    a_pt = (jnp.concatenate([rho_h, rho_k, r_i]) if a_t
+            else jnp.concatenate([rho_h, r_i, rho_k]))
+    b_pt = (jnp.concatenate([rho_h, r_j, rho_k]) if b_t
+            else jnp.concatenate([rho_h, rho_k, r_j]))
+    ctx.check_eq(ctx.claim(A, a_pt), finals[1], "head matmul A eval")
+    ctx.check_eq(ctx.claim(B, b_pt), finals[2], "head matmul B eval")
+    return t
+
+
+def g_int_matmul_heads(ctx: Ctx, A_hi: View, A_lo: View, B_hi: View,
+                       B_lo: View, shape: Tuple[int, int, int], lh: int,
+                       a_t: bool = False, b_t: bool = False
+                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """g_int_matmul for 2^lh heads at once: returns (acc~(r_h, r_i, r_j),
+    r_h ++ r_i ++ r_j), acc being every head's limb accumulator stored
+    head after head.  The centring terms carry over linearly: row sums of
+    A' are a claim at (r_h, r_i, 1/2...), column sums of B' at
+    (r_h, 1/2..., r_j), and 128^2 k is unchanged as sum_h eq(r_h, h) = 1.
+    With lh == 0 this is g_int_matmul itself, draw for draw.
+    """
+    if not lh:
+        acc, r_i, r_j = g_int_matmul(ctx, A_hi, A_lo, B_hi, B_lo, shape,
+                                     a_t=a_t, b_t=b_t)
+        return acc, jnp.concatenate([r_i, r_j])
+    n, k, m = shape
+    assert 16384 * k < F.P // 2, "k exceeds limb-accumulator bound"
+    ln, lk, lm = (x.bit_length() - 1 for x in (n, k, m))
+    r_h = ctx.challenge_point(lh)
+    r_i = ctx.challenge_point(ln)
+    r_j = ctx.challenge_point(lm)
+    Ac = vaff([(256, A_hi), (1, A_lo)], const=-(128 * 256 + 128))
+    Bc = vaff([(256, B_hi), (1, B_lo)], const=-(128 * 256 + 128))
+    t_cc = g_matmul_term_heads(ctx, Ac, Bc, shape, r_h, r_i, r_j, a_t, b_t)
+    hk = jnp.asarray(_half_point(lk))
+    a_pt = (jnp.concatenate([r_h, hk, r_i]) if a_t
+            else jnp.concatenate([r_h, r_i, hk]))
+    b_pt = (jnp.concatenate([r_h, r_j, hk]) if b_t
+            else jnp.concatenate([r_h, hk, r_j]))
+    rs = F.f4mul(_fc(k), ctx.claim(Ac, a_pt))
+    cs = F.f4mul(_fc(k), ctx.claim(Bc, b_pt))
+    acc = f4_lincomb([(1, t_cc), (128, rs), (128, cs)],
+                     const=(128 * 128 * k) % F.P)
+    return acc, jnp.concatenate([r_h, r_i, r_j])

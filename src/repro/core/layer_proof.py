@@ -208,6 +208,8 @@ def prove_layer(cfg: B.BlockCfg, layer_index: int, wt: WeightCommit,
         C.flush_lookups(ctx)
     with spans.span("layer.openings"):
         ctx.finalize()
+    for name, n in tape_counts(ctx.tape).items():
+        spans.count(f"tape.{name}", n)
     return LayerProof(layer_index=layer_index, in_root=b_in.root,
                       out_root=b_out.root, wt_root=wt.root, tape=ctx.tape)
 
@@ -254,3 +256,14 @@ def verify_layer(cfg: B.BlockCfg, proof: LayerProof, wt_root: np.ndarray,
     except C.ProofError:
         return False
     return True
+
+
+def tape_counts(tape: List) -> Dict[str, int]:
+    """A finished layer tape's sum-checks (``SumcheckProof`` objects, the
+    openings' claim-folding ones included) and claimed values (``"val"``
+    entries: leaf claims and claimed sums)."""
+    from .sumcheck import SumcheckProof
+    objs = [it[1] if it[0] == "obj" else it[2].batch_sc
+            for it in tape if it[0] in ("obj", "open")]
+    return {"sumchecks": sum(isinstance(o, SumcheckProof) for o in objs),
+            "vals": sum(1 for it in tape if it[0] == "val")}

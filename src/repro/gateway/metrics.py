@@ -14,7 +14,7 @@ from __future__ import annotations
 import threading
 from typing import Dict, List, Optional, Sequence
 
-from repro.runtime.engine import PHASES
+from repro.runtime.engine import PHASES, TAPE_COUNTS
 
 
 class Histogram:
@@ -66,7 +66,9 @@ class GatewayMetrics:
     (``forward``, ``commit``, ``prove``, ``total``), its layer-proof
     phases summed over a window (``witness``, ``argument``, ``lookups``,
     ``openings``), its compile wait (``compile``), and the transport's
-    encoding and sending of each attestation (``deliver``).
+    encoding and sending of each attestation (``deliver``); and beside
+    them ``tape_counts``, the sum-checks and claimed values of the layer
+    proofs, summed over every completed batch.
     """
 
     def __init__(self):
@@ -85,6 +87,7 @@ class GatewayMetrics:
             stage: Histogram(LATENCY_BOUNDS)
             for stage in ("forward", "commit", "prove", "total",
                           *PHASES, "compile", "deliver")}
+        self.tape_counts = {c: 0 for c in TAPE_COUNTS}
 
     # -- recording ----------------------------------------------------------
     def on_admit(self, depth: int) -> None:
@@ -126,6 +129,8 @@ class GatewayMetrics:
                     self.stage_seconds[phase].observe(
                         report.phase_seconds[phase])
                 self.stage_seconds["compile"].observe(report.compile_seconds)
+                for c in TAPE_COUNTS:
+                    self.tape_counts[c] += report.tape_counts[c]
 
     def on_delivered(self, seconds: float) -> None:
         """One attestation encoded and sent, before its ``DONE``."""
@@ -152,6 +157,7 @@ class GatewayMetrics:
                     self.admission_wait_seconds.to_dict(),
                 "stage_seconds": {k: h.to_dict()
                                   for k, h in self.stage_seconds.items()},
+                "tape_counts": dict(self.tape_counts),
             }
 
 

@@ -261,9 +261,8 @@ class ForwardTrace:
 class EngineReport:
     """Host seconds of one engine call, from its spans (``repro.spans``):
     the three stages (``total_seconds`` is their sum), the ``PHASES`` of
-    its layer proofs summed over them (and so over every worker thread
-    that proved one), and the compile wait on the threads that worked
-    for it."""
+    its layer proofs summed over every worker thread that proved one, the
+    compile wait on those threads, and the layer tapes' ``TAPE_COUNTS``."""
     forward_seconds: float
     commit_seconds: float
     prove_seconds: float
@@ -274,6 +273,7 @@ class EngineReport:
     losses: int
     phase_seconds: Dict[str, float]
     compile_seconds: float
+    tape_counts: Dict[str, int]
 
 
 @dataclasses.dataclass
@@ -534,6 +534,9 @@ from repro import spans  # noqa: E402
 #: the phases of a layer proof, each a span ``layer.<phase>`` in
 #: ``layer_proof.prove_layer``
 PHASES = ("witness", "argument", "lookups", "openings")
+#: what ``layer_proof.tape_counts`` counts on each finished layer tape,
+#: each a count ``tape.<name>``
+TAPE_COUNTS = ("sumchecks", "vals")
 
 
 def _report(report_cls, col: spans.Collector, stats: ScheduleStats,
@@ -546,4 +549,5 @@ def _report(report_cls, col: spans.Collector, stats: ScheduleStats,
         workers=stats.workers, jobs=stats.jobs, claims=stats.claims,
         losses=stats.losses,
         phase_seconds={p: col.seconds(f"layer.{p}") for p in PHASES},
-        compile_seconds=col.seconds(spans.COMPILE), **kw)
+        compile_seconds=col.seconds(spans.COMPILE),
+        tape_counts={c: col.count(f"tape.{c}") for c in TAPE_COUNTS}, **kw)

@@ -21,6 +21,9 @@ threads, such as the compile-ahead pool or a client's verification, are
 charged to no call.  A compile event nested in another of the three (an inner
 jit traced inside an outer one) is counted once, in the outer.
 
+``count(name, n)`` adds ``n`` to a count of the engine call, read back
+as ``Collector.count(name)``.
+
 ``with recording(capacity) as rec:`` keeps the last ``capacity`` closed
 spans in ``rec`` as ``(name, parent, call id, thread name, start,
 end)``, stamps in ``time.monotonic_ns()``; ``wall_ns`` maps a stamp onto
@@ -67,6 +70,10 @@ class Collector:
 
     def seconds(self, name: str) -> float:
         return sum(p.get(name, 0) for p in self._parts) / 1e9
+
+    def count(self, name: str) -> int:
+        """The sum of ``count(name, n)`` over the call's threads."""
+        return sum(p.get(name, 0) for p in self._parts)
 
 
 class _State(threading.local):
@@ -192,3 +199,11 @@ def recording(capacity: int):
 def wall_ns(t: int) -> int:
     """The ``time.time_ns()`` of the ``time.monotonic_ns()`` stamp ``t``."""
     return t + time.time_ns() - time.monotonic_ns()
+
+
+def count(name: str, n: int) -> None:
+    """Add ``n`` to the count ``name`` of this thread's engine call, if it
+    works for one."""
+    part = _STATE.part
+    if part is not None:
+        part[name] = part.get(name, 0) + n

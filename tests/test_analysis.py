@@ -110,6 +110,24 @@ def test_tape_clean_tree(golden_log):
     assert tape_lint.replay_checks(golden_log) == []
 
 
+@pytest.fixture(scope="session")
+def multi_head_log():
+    """The golden prove at 4 heads: the attention matmuls take the
+    argument batched over heads, which the 1-head prove never enters."""
+    from repro.analysis.replay import run_golden_prove
+    return run_golden_prove(heads=4)
+
+
+def test_fs_clean_multi_head(multi_head_log):
+    from repro.analysis import fs_lint
+    assert fs_lint.replay_checks(multi_head_log) == []
+
+
+def test_tape_clean_multi_head(multi_head_log):
+    from repro.analysis import tape_lint
+    assert tape_lint.replay_checks(multi_head_log) == []
+
+
 def test_golden_log_sees_the_prover(golden_log):
     # the replay harness must actually observe a prover, or every
     # replay check would pass vacuously

@@ -2,7 +2,8 @@
 (``repro.spans``): a layer proof's four phases charged to the engine
 call that dispatched it, on worker threads too; compile events charged
 only on a thread with an open span of a call; the recorder; the
-gateway's snapshot of the phase, compile and deliver histograms.
+gateway's snapshot of the phase, compile and deliver histograms; the
+layer tapes' counts in the engine's report and the gateway's snapshot.
 """
 import concurrent.futures
 import json
@@ -19,7 +20,7 @@ from repro.core import blocks as B
 from repro.core import layer_proof as LP
 from repro.gateway import AttestationGateway, GatewayClient
 from repro.kernels import ahead as AH
-from repro.runtime.engine import PHASES, ProverEngine
+from repro.runtime.engine import PHASES, TAPE_COUNTS, ProverEngine
 
 CFG = B.BlockCfg(family="gpt2", d=16, dff=32, heads=2, kv_heads=2, dh=8,
                  seq=8)
@@ -81,6 +82,22 @@ def test_phases_are_charged_to_the_call(model, call, workers):
     assert report.total_seconds == pytest.approx(
         report.forward_seconds + report.commit_seconds
         + report.prove_seconds)
+
+
+@pytest.mark.parametrize("workers", [1, 2])
+def test_tape_counts_are_charged_to_the_call(model, workers):
+    """The sum-checks and claimed values of every layer tape the call
+    proved, on whichever worker thread, summed in its report."""
+    svc, xs = model
+    eng = ProverEngine(svc.block_cfgs, svc.weights,
+                       svc.engine_for(QUERIES).params,
+                       weight_cache=svc.weight_cache, workers=workers)
+    proofs, report = eng.prove_many(xs)
+    tapes = [lp.tape for p in proofs for lp in p.layer_proofs]
+    want = {k: sum(LP.tape_counts(t)[k] for t in tapes)
+            for k in TAPE_COUNTS}
+    assert report.tape_counts == want
+    assert 0 < want["sumchecks"] < want["vals"]
 
 
 def test_compile_in_a_span_is_charged():
@@ -185,3 +202,6 @@ def test_gateway_snapshot_has_phase_compile_and_deliver(model):
         assert stages[key]["count"] == 2 and stages[key]["sum"] > 0, key
     assert stages["compile"]["count"] == 2
     assert stages["deliver"]["sum"] > 0
+    counts = json.loads(json.dumps(snap))["tape_counts"]
+    assert set(counts) == set(TAPE_COUNTS)
+    assert 0 < counts["sumchecks"] < counts["vals"]
